@@ -77,7 +77,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
-from repro.compat import cost_analysis_dict
 from repro.configs import get_smoke_config
 from repro.core.control_plane import capacity_for, route_topk, route_topk_decode
 from repro.models.model import Model
@@ -220,8 +219,8 @@ def _bench_spec(cfg) -> dict:
     draft0 = jnp.tile(toks[:, None], (1, SPEC_T))
     cacheT = mT.init_cache(BATCH, max_len)
     _, cacheT = jax.jit(mT.prefill)(params, prompts, cacheT)
-    cost1 = cost_analysis_dict(decode1.lower(params, cache1, toks, jnp.int32(PROMPT)).compile())
-    costT = cost_analysis_dict(decodeT.lower(params, cacheT, draft0, lens, acc).compile())
+    cost1 = decode1.lower(params, cache1, toks, jnp.int32(PROMPT)).compile().cost_analysis()
+    costT = decodeT.lower(params, cacheT, draft0, lens, acc).compile().cost_analysis()
     bytes_seq = float(cost1.get("bytes accessed", 0.0))
     bytes_spec = float(costT.get("bytes accessed", 0.0))
 
@@ -320,15 +319,15 @@ def _bench_tree(cfg) -> dict:
     path0 = jnp.tile(jnp.arange(T, dtype=jnp.int32)[None, :], (BATCH, 1))
     cacheT = mT.init_cache(BATCH, max_len)
     _, cacheT = jax.jit(mT.prefill)(params, prompts, cacheT)
-    bytes_lin = float(cost_analysis_dict(
-        lin.lower(params, cacheT, toks0, lens0, acc0).compile()
-    ).get("bytes accessed", 0.0))
-    bytes_tree = float(cost_analysis_dict(
-        trl.lower(params, cacheT, toks0, lens0, acc0).compile()
-    ).get("bytes accessed", 0.0))
-    bytes_commit = float(cost_analysis_dict(
-        commit.lower(cacheT, lens0, path0).compile()
-    ).get("bytes accessed", 0.0))
+    bytes_lin = float(
+        lin.lower(params, cacheT, toks0, lens0, acc0).compile().cost_analysis().get("bytes accessed", 0.0)
+    )
+    bytes_tree = float(
+        trl.lower(params, cacheT, toks0, lens0, acc0).compile().cost_analysis().get("bytes accessed", 0.0)
+    )
+    bytes_commit = float(
+        commit.lower(cacheT, lens0, path0).compile().cost_analysis().get("bytes accessed", 0.0)
+    )
 
     V = cfg.vocab_size
 
@@ -438,7 +437,7 @@ def _bench_rolling(cfg) -> dict:
         lowered = jax.jit(model.decode_tokens).lower(
             params, cache, toks, lens, jnp.zeros((B,), jnp.int32)
         )
-        out[tag] = float(cost_analysis_dict(lowered.compile()).get("bytes accessed", 0.0))
+        out[tag] = float(lowered.compile().cost_analysis().get("bytes accessed", 0.0))
     return {"window": W, "bytes_1x": out["1x"], "bytes_8x": out["8x"]}
 
 
@@ -1187,7 +1186,6 @@ def _bench_paged(cfg) -> dict:
 # ---------------------------------------------------------------------------
 
 _SHARDED_CODE = """
-import repro.compat as _compat; _compat.install_shard_map()
 import dataclasses, json, re
 import jax, jax.numpy as jnp
 if len(jax.devices()) < 8:
@@ -1195,7 +1193,6 @@ if len(jax.devices()) < 8:
     # have to guess from a traceback whether devices were the problem
     print(f"SKIP only {len(jax.devices())} host device(s) came up (need 8)")
     raise SystemExit(0)
-from repro.compat import cost_analysis_dict
 from repro.configs import get_smoke_config
 from repro.configs.base import ShapeCell
 from repro.launch.mesh import make_host_mesh
@@ -1237,8 +1234,8 @@ out = {
     "gathered_tiles_in_fallback_hlo": sum(hlo_f.count(t) for t in tiles),
     "slot_tensors_in_sharded_hlo": len(slot_re.findall(hlo_s)),
     "psum_ops_per_launch": hlo_s.count(" all-reduce("),
-    "bytes_accessed_sharded": float(cost_analysis_dict(sharded).get("bytes accessed", 0.0)),
-    "bytes_accessed_fallback": float(cost_analysis_dict(fallback).get("bytes accessed", 0.0)),
+    "bytes_accessed_sharded": float(sharded.cost_analysis().get("bytes accessed", 0.0)),
+    "bytes_accessed_fallback": float(fallback.cost_analysis().get("bytes accessed", 0.0)),
 }
 print("RESULT " + json.dumps(out))
 """
@@ -1259,6 +1256,9 @@ def _bench_sharded():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(_REPO_ROOT / "src")
+    # the child builds 8 virtual CPU devices; it must never reach for the
+    # accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         proc = subprocess.run(
             [sys.executable, "-c", _SHARDED_CODE],
@@ -1589,4 +1589,7 @@ def _emit_json(results: dict) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

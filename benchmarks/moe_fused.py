@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit
-from repro.compat import cost_analysis_dict
 from repro.configs import get_smoke_config
 from repro.core.control_plane import capacity_for, route_topk
 from repro.models import moe as moe_mod
@@ -84,7 +83,7 @@ def _bench(cfg, p, x, rs, plane: str, mode: str) -> dict:
     else:
         fn = jax.jit(_data_plane_fn(cfg, p, C, plane, mode))
     lowered = fn.lower(x, rs)
-    cost = cost_analysis_dict(lowered.compile())
+    cost = lowered.compile().cost_analysis()
     n_ecd = lowered.as_text().count(f"tensor<{cfg.num_experts}x{C}x{cfg.d_model}x")
     fn(x, rs)  # warm
     t0 = time.perf_counter()
@@ -139,4 +138,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

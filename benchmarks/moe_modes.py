@@ -11,7 +11,6 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit
-from repro.compat import cost_analysis_dict
 from repro.configs import get_smoke_config
 from repro.core.control_plane import capacity_for, route_topk
 from repro.models import moe as moe_mod
@@ -27,7 +26,7 @@ def run() -> list:
         c = dataclasses.replace(cfg, route_mode=mode)
         rs = x if mode == "lookahead" else None
         fn = jax.jit(lambda xx, m=c, r=rs: moe_mod.moe_layer(xx, r if r is not None else None, p, m)[0])
-        cost = cost_analysis_dict(fn.lower(x).compile())
+        cost = fn.lower(x).compile().cost_analysis()
         flops = cost.get("flops", 0.0)
         fn(x)  # warm
         t0 = time.perf_counter()
@@ -56,4 +55,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

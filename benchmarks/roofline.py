@@ -70,4 +70,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

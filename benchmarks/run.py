@@ -39,4 +39,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())

@@ -4,8 +4,9 @@ the control-plane byte share.
 
     PYTHONPATH=src python examples/serve_moe.py --batch 4 --prompt-len 64 --gen 32
 
-``--fused`` serves through the fused Pallas data plane (kernels/moe_fused;
-interpret-mode off-TPU) instead of the reference dispatch/combine plane.
+``--kernels`` runs the Pallas kernels off-TPU too (interpret mode; on TPU
+they always run): grouped-GEMM experts, flash-decode attention and the
+plan-steered decode MoE.
 ``--decode-plane`` serves decode through the Agile decode plane: the next
 step's DecodePlan is carried in the KV cache (router runs during the previous
 step's FFN), dispatch is capacity-sort-free, and attention reads only the
@@ -45,8 +46,8 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
-    ap.add_argument("--fused", action="store_true",
-                    help="use the fused gather->GEMM->scatter MoE data plane")
+    ap.add_argument("--kernels", action="store_true",
+                    help="run the Pallas kernels off-TPU (interpret mode)")
     ap.add_argument("--decode-plane", action="store_true",
                     help="decode through the Agile decode plane (plan in "
                          "cache, no capacity sort, prefix-only attention)")
@@ -75,7 +76,7 @@ def main() -> None:
         args.decode_plane = True
 
     cfg = get_smoke_config("qwen3-moe-235b-a22b")
-    if args.fused:
+    if args.kernels:
         cfg = dataclasses.replace(cfg, use_pallas=True)
     if args.decode_plane:
         cfg = dataclasses.replace(cfg, decode_plane=True)

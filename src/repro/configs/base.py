@@ -98,15 +98,17 @@ class ModelConfig:
     param_dtype: str = "float32"
     # Quantized bandwidth plane (serve): "" = full precision, "int8" = store
     # KV (per-token symmetric scales, dequantized in-kernel after the tile
-    # load) / decode expert stacks (per-expert scales read from SMEM next to
-    # the plan's expert ids) in int8.  The scales are control words on the
-    # same scalar-prefetch path as lengths / plans / ancestor masks / block
-    # tables — see core/quant.py and docs/architecture.md.
+    # load; the scale rows follow the KV tile through the same clamped index
+    # map) / decode expert stacks (per-expert scales read from SMEM next to
+    # the plan's expert ids) in int8 — see core/quant.py and
+    # docs/architecture.md.
     kv_dtype: str = ""
     expert_dtype: str = ""
     optimizer: str = "adamw"  # adamw | adafactor
     remat: bool = True
-    use_pallas: bool = False  # kernels are TPU-target; interpret-mode in tests
+    # off TPU, run the Pallas kernels in interpret mode (CPU parity tests); on
+    # TPU they always run (see repro.kernels.use_kernels)
+    use_pallas: bool = False
     # analysis twins: unroll inner scans (KV blocks / SSD chunks) so that
     # compiled cost_analysis is exact — lax.scan bodies are otherwise counted
     # once by HloCostAnalysis regardless of trip count (see launch/dryrun.py)
@@ -234,11 +236,17 @@ def cells_for(cfg: ModelConfig) -> List[ShapeCell]:
 
 _REGISTRY: Dict[str, ModelConfig] = {}
 _SMOKE: Dict[str, ModelConfig] = {}
+_PRESETS: set = set()
 
 
-def register(full: ModelConfig, smoke: ModelConfig) -> ModelConfig:
+def register(full: ModelConfig, smoke: ModelConfig, *, preset: bool = False) -> ModelConfig:
+    """Register an architecture, or with ``preset=True`` a chip-sized cut of
+    one: reachable by name like an architecture, but not listed by
+    :func:`list_archs`."""
     _REGISTRY[full.name] = full
     _SMOKE[full.name] = smoke
+    if preset:
+        _PRESETS.add(full.name)
     return full
 
 
@@ -255,7 +263,7 @@ def get_smoke_config(name: str) -> ModelConfig:
 
 
 def list_archs() -> List[str]:
-    return sorted(_REGISTRY)
+    return sorted(n for n in _REGISTRY if n not in _PRESETS)
 
 
 def shrink(

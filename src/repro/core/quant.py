@@ -5,10 +5,10 @@ split:
 
 * wire (``parallel/collectives``): per-tensor scales ride the gradient
   all-reduce as 4-byte control words next to the int8 payload.
-* serve (quantized bandwidth plane): per-token KV scales and per-expert
-  weight scales ride the scalar-prefetch path next to lengths, plans,
-  ancestor words, and block tables — the data plane streams int8, the
-  control plane carries the scales.
+* serve (quantized bandwidth plane): per-expert weight scales ride the
+  scalar-prefetch path next to the plan's expert ids, and per-token KV
+  scales follow their KV tile through the index map that lengths and block
+  tables steer — the data plane streams int8 with its scales beside it.
 
 ``axis=`` selects blockwise scales: the amax reduces over the given axes
 (keepdims) so the returned scale broadcasts against the quantized tensor —

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -137,7 +136,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,11 +1,12 @@
 """jit'd wrapper: (B, S, n, hd) layout in/out, padding to block multiples."""
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax.numpy as jnp
 
-from repro.kernels import on_tpu
+from repro.kernels import resolve_interpret, shard_over_heads
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
@@ -20,7 +21,14 @@ def flash_attention(
     bkv: int = 128,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    it = (not on_tpu()) if interpret is None else interpret
+    run = functools.partial(
+        _flash_attention, causal=causal, window=window, bq=bq, bkv=bkv, interpret=interpret
+    )
+    return shard_over_heads(run, (q, k, v), (2, 2, 2))
+
+
+def _flash_attention(q, k, v, *, causal, window, bq, bkv, interpret):
+    it = resolve_interpret(interpret)
     B, Sq, nq, hd = q.shape
     Skv = k.shape[1]
     bq_ = min(bq, Sq)

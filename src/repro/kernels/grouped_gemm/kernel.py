@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _gg_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
@@ -76,7 +75,7 @@ def grouped_gemm_pallas(
         out_specs=pl.BlockSpec((1, bm, bn), lambda e, m, n, k: (e, m, n)),
         out_shape=jax.ShapeDtypeStruct((E, Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -14,10 +14,13 @@ path instead and *steer the weight DMA itself*:
   the weight stream.  Compute per step is exactly one token row through one
   expert's SwiGLU tile; the f-tile axis keeps the three weight tiles within
   VMEM at production d_ff.
-* the (T, d) f32 output block is revisited across the sequential grid:
+* tokens move in sublane-aligned blocks of ``TOKEN_BLOCK`` rows: step
+  (t, j, n) reads row t % TOKEN_BLOCK of token block t // TOKEN_BLOCK, and
+  the matching f32 output block is revisited across the sequential grid —
   per-assignment results accumulate in place scaled by the SMEM combine
-  weight (the scatter-combine is the GEMM epilogue, like moe_fused, but with
-  token-major slots so no slot->token indirection is needed at all).
+  weight (the scatter-combine is the GEMM epilogue, with token-major slots
+  so no slot->token indirection is needed at all).  Work per step is
+  O(TOKEN_BLOCK * d), independent of T.
 
 This is the Agile-PE-Assignment shape of the paper applied to decode: the
 loop body (one token per sequence) is far too small to fill the prefill
@@ -35,7 +38,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+# tokens per x / output block: the TPU compiler accepts a dynamic row only at
+# tile-aligned offsets, so a block holds a sublane-aligned group of rows and
+# the kernel selects row t inside it
+TOKEN_BLOCK = 8
 
 
 def _pad_axis(a: jnp.ndarray, axis: int, mult: int, value=0) -> jnp.ndarray:
@@ -53,11 +59,15 @@ def _decode_moe_kernel(
 ):
     t, j, n = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    @pl.when((t == 0) & (j == 0) & (n == 0))
-    def _init():
+    @pl.when((t % TOKEN_BLOCK == 0) & (j == 0) & (n == 0))
+    def _init():  # first visit of this token block's output rows
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    row = x_ref[...].astype(jnp.float32)  # (1, d) token row for assignment (t, j)
+    # row t of its token block, selected with a mask-sum (adding zeros is
+    # exact) instead of a dynamic sublane slice
+    rows = jax.lax.broadcasted_iota(jnp.int32, (TOKEN_BLOCK, 1), 0)
+    is_t = rows == t % TOKEN_BLOCK
+    row = jnp.sum(jnp.where(is_t, x_ref[...].astype(jnp.float32), 0.0), axis=0, keepdims=True)  # (1, d)
     wg = wg_ref[0].astype(jnp.float32)
     wu = wu_ref[0].astype(jnp.float32)
     wd = wd_ref[0].astype(jnp.float32)
@@ -78,8 +88,16 @@ def _decode_moe_kernel(
     # the assignment's router weight from SMEM.  Padded f-tiles contribute
     # silu(0)*0 = 0, so accumulating across n needs no masking.
     w = w_ref[t * k + j]
-    cur = pl.load(o_ref, (pl.ds(t, 1), slice(None)))
-    pl.store(o_ref, (pl.ds(t, 1), slice(None)), cur + w * y)
+    o_ref[...] = o_ref[...] + jnp.where(is_t, w * y, 0.0)
+
+
+def _vmem_limit(d: int, bf: int, itemsize: int) -> int:
+    """Scoped VMEM this launch needs: three double-buffered weight tiles,
+    their f32 working copies, and the double-buffered token input and f32
+    output blocks — with headroom, capped below the chip's 128 MiB."""
+    tiles = 3 * d * bf * (2 * itemsize + 4)
+    tokens = 2 * TOKEN_BLOCK * d * (4 + 4)
+    return int(min(tiles + tokens + (8 << 20), 100 << 20))
 
 
 @functools.partial(jax.jit, static_argnames=("bf", "quantized", "interpret"))
@@ -92,7 +110,7 @@ def decode_moe_pallas(
     w_down: jnp.ndarray,      # (E, f, d)
     scales: jnp.ndarray = None,  # (3, E) f32 per-expert gate/up/down scales
     *,
-    bf: int = 512,
+    bf: int = 256,
     quantized: bool = False,
     interpret: bool = False,
 ) -> jnp.ndarray:
@@ -110,7 +128,17 @@ def decode_moe_pallas(
     wu = _pad_axis(w_up, 2, bf)
     wd = _pad_axis(w_down, 1, bf)
     nf = wg.shape[-1] // bf
+    xp = _pad_axis(x, 0, TOKEN_BLOCK)  # whole token blocks; the grid visits only real rows
     grid = (T, k, nf)
+
+    def tile_in(t, j, n, ids_ref, *_):  # (E, d, f) stacks: expert, 0, f-tile
+        return (ids_ref[t * k + j], 0, n)
+
+    def tile_out(t, j, n, ids_ref, *_):  # (E, f, d) stack: expert, f-tile, 0
+        return (ids_ref[t * k + j], n, 0)
+
+    def token_block(t, j, n, *_):
+        return (t // TOKEN_BLOCK, 0)
 
     out = pl.pallas_call(
         functools.partial(_decode_moe_kernel, k=k, quantized=quantized),
@@ -118,21 +146,23 @@ def decode_moe_pallas(
             num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, d), lambda t, j, n, ids_ref, w_ref, scl_ref: (t, 0)),
+                pl.BlockSpec((TOKEN_BLOCK, d), token_block),
                 # the plan steers the DMA: only the selected expert's tiles move
-                pl.BlockSpec((1, d, bf), lambda t, j, n, ids_ref, w_ref, scl_ref: (ids_ref[t * k + j], 0, n)),
-                pl.BlockSpec((1, d, bf), lambda t, j, n, ids_ref, w_ref, scl_ref: (ids_ref[t * k + j], 0, n)),
-                pl.BlockSpec((1, bf, d), lambda t, j, n, ids_ref, w_ref, scl_ref: (ids_ref[t * k + j], n, 0)),
+                pl.BlockSpec((1, d, bf), tile_in),
+                pl.BlockSpec((1, d, bf), tile_in),
+                pl.BlockSpec((1, bf, d), tile_out),
             ],
-            # whole (T, d) f32 accumulator revisited across the sequential
-            # grid, flushed to HBM once at the end
-            out_specs=pl.BlockSpec((T, d), lambda t, j, n, ids_ref, w_ref, scl_ref: (0, 0)),
+            # f32 accumulator of a token block, revisited by its rows'
+            # consecutive grid steps and flushed to HBM when the block moves on
+            out_specs=pl.BlockSpec((TOKEN_BLOCK, d), token_block),
         ),
-        out_shape=jax.ShapeDtypeStruct((T, d), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct(xp.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             # scatter-accumulate into a shared output block: strictly sequential
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(d, bf, jnp.dtype(wg.dtype).itemsize),
         ),
         interpret=interpret,
-    )(ids, ws, scales.astype(jnp.float32), x, wg, wu, wd)
-    return out
+        name="moe_decode",
+    )(ids, ws, scales.astype(jnp.float32), xp, wg, wu, wd)
+    return out[:T]

@@ -5,7 +5,8 @@ expert stacks in one plan-steered Pallas launch on TPU; off-TPU it runs the
 jnp gather oracle (which is also the fastest CPU shape at tiny T — the
 interpreter's per-step cost would dominate a T*k-step grid).  Pass
 ``interpret=True`` to force the kernel through the interpreter (parity
-tests).
+tests), or ``interpret=False`` to lower it for the TPU compiler off-TPU
+(compile rehearsals).
 """
 from __future__ import annotations
 
@@ -14,7 +15,7 @@ from typing import Optional
 import jax.numpy as jnp
 
 from repro.core.plans import DecodePlan
-from repro.kernels import on_tpu
+from repro.kernels import on_tpu, resolve_interpret
 from repro.kernels.moe_decode import ref
 from repro.kernels.moe_decode.kernel import decode_moe_pallas
 
@@ -33,33 +34,19 @@ def decode_moe(
     decode path consumes the int8 stacks + per-expert scale control words —
     the f32 stacks stay untouched for prefill/train.
     """
-    if "w_gate_q" in p:
-        scales = jnp.stack(
-            [p["w_gate_s"], p["w_up_s"], p["w_down_s"]]
-        ).astype(jnp.float32)
-        if interpret is None and not on_tpu():
-            y = ref.decode_moe(
-                x, plan.expert_ids, plan.weights,
-                p["w_gate_q"], p["w_up_q"], p["w_down_q"], scales=scales,
-            )
-        else:
-            y = decode_moe_pallas(
-                x, plan.expert_ids, plan.weights,
-                p["w_gate_q"], p["w_up_q"], p["w_down_q"], scales,
-                quantized=True, interpret=bool(interpret),
-            )
-    elif interpret is None and not on_tpu():
-        y = ref.decode_moe(
-            x, plan.expert_ids, plan.weights, p["w_gate"], p["w_up"], p["w_down"]
-        )
+    quantized = "w_gate_q" in p
+    if quantized:
+        stacks = (p["w_gate_q"], p["w_up_q"], p["w_down_q"])
+        scales = jnp.stack([p["w_gate_s"], p["w_up_s"], p["w_down_s"]]).astype(jnp.float32)
     else:
+        stacks, scales = (p["w_gate"], p["w_up"], p["w_down"]), None
+    if interpret is None and not on_tpu():
+        y = ref.decode_moe(x, plan.expert_ids, plan.weights, *stacks, scales=scales)
+    else:
+        if not quantized:
+            stacks = tuple(w.astype(x.dtype) for w in stacks)
         y = decode_moe_pallas(
-            x,
-            plan.expert_ids,
-            plan.weights,
-            p["w_gate"].astype(x.dtype),
-            p["w_up"].astype(x.dtype),
-            p["w_down"].astype(x.dtype),
-            interpret=bool(interpret),
+            x, plan.expert_ids, plan.weights, *stacks, scales,
+            quantized=quantized, interpret=resolve_interpret(interpret),
         )
     return y.astype(x.dtype)

@@ -9,12 +9,9 @@ from typing import Optional
 import jax.numpy as jnp
 
 from repro.core.plans import DispatchPlan
-from repro.kernels import on_tpu
+from repro.kernels import resolve_interpret
 from repro.kernels.moe_dispatch.kernel import combine_pallas, dispatch_pallas
 
-
-def _resolve(interpret: Optional[bool]) -> bool:
-    return (not on_tpu()) if interpret is None else interpret
 
 
 def dispatch(x: jnp.ndarray, plan: DispatchPlan, *, interpret: Optional[bool] = None) -> jnp.ndarray:
@@ -24,7 +21,7 @@ def dispatch(x: jnp.ndarray, plan: DispatchPlan, *, interpret: Optional[bool] = 
     return dispatch_pallas(
         x_pad, plan.flat_dispatch_idx(),
         num_experts=plan.num_experts, capacity=plan.capacity,
-        interpret=_resolve(interpret),
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -36,5 +33,5 @@ def combine(y_slots: jnp.ndarray, plan: DispatchPlan, *, interpret: Optional[boo
         [y_slots.reshape(E * C, d), jnp.zeros((1, d), y_slots.dtype)], axis=0
     )
     cidx, w = plan.flat_combine_words()
-    out = combine_pallas(y_pad, cidx, w, top_k=k, interpret=_resolve(interpret))
+    out = combine_pallas(y_pad, cidx, w, top_k=k, interpret=resolve_interpret(interpret))
     return out.astype(y_slots.dtype)

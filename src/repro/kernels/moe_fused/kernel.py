@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _pad_axis(a: jnp.ndarray, axis: int, mult: int, value=0) -> jnp.ndarray:
@@ -74,8 +73,7 @@ def _gather_swiglu_kernel(idx_ref, x_ref, wg_ref, wu_ref, h_ref, xs_ref, *, bm: 
 
         def body(r, carry):
             tok = idx_ref[base + r]  # control word from SMEM
-            row = pl.load(x_ref, (pl.ds(tok, 1), slice(None)))
-            pl.store(xs_ref, (pl.ds(r, 1), slice(None)), row)
+            xs_ref[pl.ds(r, 1), :] = x_ref[pl.ds(tok, 1), :]
             return carry
 
         jax.lax.fori_loop(0, bm, body, 0)
@@ -129,7 +127,7 @@ def fused_gather_swiglu_pallas(
             scratch_shapes=[pltpu.VMEM((bm, d), x_pad.dtype)],
         ),
         out_shape=jax.ShapeDtypeStruct((E, Cp, fp), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # e/c may split across cores; n must stay sequential so the
             # gathered scratch tile from n == 0 is still live for n > 0
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -162,8 +160,7 @@ def _down_combine_kernel(idx_ref, w_ref, h_ref, wd_ref, o_ref, *, bm: int, cap_p
         tok = idx_ref[base + r]
         w = w_ref[base + r]
         row = jax.lax.dynamic_slice_in_dim(y, r, 1, axis=0)
-        cur = pl.load(o_ref, (pl.ds(tok, 1), slice(None)))
-        pl.store(o_ref, (pl.ds(tok, 1), slice(None)), cur + w * row)
+        o_ref[pl.ds(tok, 1), :] = o_ref[pl.ds(tok, 1), :] + w * row
         return carry
 
     jax.lax.fori_loop(0, bm, body, 0)
@@ -208,7 +205,7 @@ def fused_down_combine_pallas(
             out_specs=pl.BlockSpec((T + 1, d), lambda e, c, idx_ref, w_ref: (0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((T + 1, d), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # scatter-accumulate into a shared output block: strictly sequential
             dimension_semantics=("arbitrary", "arbitrary"),
         ),

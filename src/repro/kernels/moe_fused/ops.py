@@ -5,7 +5,10 @@ plan-steered gather + gate/up + SwiGLU, then down projection + weighted
 scatter-combine.  No (E, C, d) tensor is ever materialized — only the
 (E, C, f) hidden slots between the two launches.
 
-``interpret=None`` auto-selects: compiled on TPU, interpret elsewhere.
+Off the served path: the TPU compiler refuses the per-row gather and
+scatter of these kernels (dynamic sublane offsets, ``dynamic_slice``), so
+they run in the Pallas interpreter only, opted into with
+``moe_ffn(fused=True)``.
 """
 from __future__ import annotations
 
@@ -14,15 +17,11 @@ from typing import Optional
 import jax.numpy as jnp
 
 from repro.core.plans import DispatchPlan
-from repro.kernels import on_tpu
+from repro.kernels import resolve_interpret
 from repro.kernels.moe_fused.kernel import (
     fused_down_combine_pallas,
     fused_gather_swiglu_pallas,
 )
-
-
-def _resolve(interpret: Optional[bool]) -> bool:
-    return (not on_tpu()) if interpret is None else interpret
 
 
 def fused_gather_swiglu(
@@ -44,7 +43,7 @@ def fused_gather_swiglu(
         w_up.astype(x.dtype),
         num_experts=num_experts,
         capacity=capacity,
-        interpret=_resolve(interpret),
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -63,7 +62,7 @@ def fused_down_combine(
         flat_idx,
         slot_w,
         num_tokens=num_tokens,
-        interpret=_resolve(interpret),
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -92,8 +91,8 @@ def fused_moe_apply(
 def fused_moe_fn(
     x: jnp.ndarray, plan: DispatchPlan, p, *, interpret: Optional[bool] = None
 ) -> jnp.ndarray:
-    """Plan-level entry point used by :func:`repro.models.moe.moe_ffn` — the
-    fused default data plane (replaces dispatch -> experts_fn -> combine)."""
+    """Plan-level entry point used by :func:`repro.models.moe.moe_ffn` under
+    ``fused=True`` (replaces dispatch -> experts_fn -> combine)."""
     return fused_moe_apply(
         x,
         plan.flat_dispatch_idx(),
@@ -104,24 +103,3 @@ def fused_moe_fn(
         interpret=interpret,
     )
 
-
-def fused_experts_fn(x_slots: jnp.ndarray, p) -> jnp.ndarray:
-    """experts_fn-compatible variant (drop-in for ``local_experts_fn``):
-    slots are already in expert-major order — e.g. the post-all_to_all tensor
-    in the sharded data plane — so only the GEMM fusion is exploited: one
-    identity-gather gate/up/SwiGLU launch (no gate/up intermediates in HBM)
-    plus one parallel grouped down-projection launch.  No scatter epilogue:
-    the output stays slot-major, so the sequential combine grid would be pure
-    overhead here."""
-    from repro.kernels.grouped_gemm.kernel import grouped_gemm_pallas
-
-    E, C, d = x_slots.shape
-    T = E * C
-    flat_idx = jnp.arange(T, dtype=jnp.int32)
-    h = fused_gather_swiglu(
-        x_slots.reshape(T, d), flat_idx, p["w_gate"], p["w_up"],
-        num_experts=E, capacity=C,
-    )
-    return grouped_gemm_pallas(
-        h, p["w_down"].astype(h.dtype), interpret=_resolve(None)
-    ).astype(x_slots.dtype)

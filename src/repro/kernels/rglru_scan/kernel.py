@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, h_ref, *, bt: int):
@@ -69,7 +68,7 @@ def rglru_scan_pallas(
         out_specs=pl.BlockSpec((1, bt, bw), lambda bb, wi, ti: (bb, ti, wi)),
         out_shape=jax.ShapeDtypeStruct((B, T, W), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -5,7 +5,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-from repro.kernels import on_tpu
+from repro.kernels import resolve_interpret
 from repro.kernels.rglru_scan.kernel import rglru_scan_pallas
 
 
@@ -18,7 +18,7 @@ def rglru_scan(
     bw: int = 512,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    it = (not on_tpu()) if interpret is None else interpret
+    it = resolve_interpret(interpret)
     B, T, W = a.shape
     if h0 is None:
         h0 = jnp.zeros((B, W), jnp.float32)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, cum_ref, o_ref, hout_ref, h_ref, *, q: int, n_chunks: int):
@@ -102,7 +101,7 @@ def ssd_scan_pallas(
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

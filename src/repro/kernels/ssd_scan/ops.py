@@ -5,7 +5,7 @@ from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
-from repro.kernels import on_tpu
+from repro.kernels import resolve_interpret
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 
 
@@ -20,7 +20,7 @@ def ssd_scan(
     interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (y (B, T, H, P) f32, final state (B, H, N, P) f32)."""
-    it = (not on_tpu()) if interpret is None else interpret
+    it = resolve_interpret(interpret)
     B, T, H, P = x.shape
     q = min(chunk, T)
     pad = (-T) % q

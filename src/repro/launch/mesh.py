@@ -10,7 +10,14 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    # Auto axes: the partitioner propagates shardings from the in/out specs
+    # and ``with_sharding_constraint`` hints (jax.make_mesh defaults to
+    # Explicit axes, which reject both)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -25,17 +32,17 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     """Arbitrary mesh over available devices (tests: small host meshes)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small (data, model) mesh over however many host devices exist."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def elastic_mesh(n_healthy: int, *, model: int = 16, multi_pod: bool = False) -> Mesh:

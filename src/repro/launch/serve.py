@@ -992,7 +992,11 @@ def run_cross_process(args, cfg, requests, params, specs, ckpt, *,
     return code
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
+    """Serve the synthetic request queue described by ``argv`` (the command
+    line when None) and return the run's report: ``code`` is 0 iff every
+    exit guard held (all requests answered, no unexplained error, no
+    duplicate, no masked emission), plus the fabric's counters."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -1095,10 +1099,22 @@ def main() -> None:
                     help="admission queue high-water mark (cross-process "
                          "fabric; 0 = unbounded): submissions past it are "
                          "rejected with a counted error result")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    import sys
+    import os
     import tempfile
+
+    if args.workers > 0 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        # the supervisor builds params in JAX and each worker is a JAX
+        # process of its own; an accelerator belongs to one process, so a
+        # worker would wait forever on the device the supervisor holds
+        ap.error("--workers starts one JAX process per worker and runs only "
+                 "with JAX_PLATFORMS=cpu; on an accelerator serve in-process "
+                 "(--fabric N)")
+
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     import jax
 
@@ -1106,7 +1122,7 @@ def main() -> None:
     from repro.configs import get_config, get_smoke_config
     from repro.core.plans import TreePlan
     from repro.launch.mesh import make_host_mesh
-    from repro.models.model import Model
+    from repro.launch.steps import init_sharded_params
     from repro.parallel.sharding import param_shardings
     from repro.runtime.fabric import FabricConfig, ServeFabric
     from repro.runtime.faults import FaultInjector, parse_faults
@@ -1135,11 +1151,11 @@ def main() -> None:
     n_req = args.requests or 2 * B * args.fabric
     max_len = S + args.shared_prefix + args.gen + T
 
-    # synthetic ragged request queue: a few distinct length buckets so the
-    # per-length prefill jit cache stays small; --shared-prefix prepends one
-    # common system prompt to every request so admissions after the first
-    # bind its full pages straight from the prefix trie
-    buckets = sorted({max(4, S // 2), max(4, (3 * S) // 4), S})
+    # synthetic ragged request queue: a few distinct length buckets (S/4 to
+    # S) so the per-length prefill jit cache stays small; --shared-prefix
+    # prepends one common system prompt to every request so admissions
+    # after the first bind its full pages straight from the prefix trie
+    buckets = sorted({max(4, S // 4), max(4, S // 2), max(4, (3 * S) // 4), S})
     rng = np.random.default_rng(0)
     prog_spec = None
     if args.program:
@@ -1171,7 +1187,9 @@ def main() -> None:
         for i in range(n_req)
     ]
 
-    params = Model(cfg).init(jax.random.PRNGKey(0))
+    # created directly under their serve shardings: a model sized to fill
+    # its mesh never exists whole on one device
+    params = init_sharded_params(cfg, mesh, jax.random.PRNGKey(0))
     specs = parse_faults(args.inject)
     injector = FaultInjector(specs) if specs else None
 
@@ -1192,7 +1210,7 @@ def main() -> None:
         )
         if tmpdir is not None:
             tmpdir.cleanup()
-        sys.exit(code)
+        return {"code": code}
 
     def restore_params(mgr):
         abs_p = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
@@ -1268,25 +1286,30 @@ def main() -> None:
     unanswered = [r.rid for r in requests if r.rid not in results]
     poison_expected = any(s.kind == "poison" for s in specs)
     errors = [r for r in results.values() if r.error is not None]
+    code = 0
     if unanswered:
         print(f"FABRIC ERROR: {len(unanswered)} requests unanswered: {unanswered}")
-        sys.exit(1)
+        code = 1
     if errors and not poison_expected:
         print(f"FABRIC ERROR: {len(errors)} requests errored without poison "
               f"injection: {[(r.rid, r.error) for r in errors]}")
-        sys.exit(1)
+        code = 1
     if st["duplicates"]:
         print(f"FABRIC ERROR: {st['duplicates']} duplicate results published")
-        sys.exit(1)
+        code = 1
     if args.expect_shared_pages and st["pages_shared"] == 0:
         print("FABRIC ERROR: --expect-shared-pages set but no page was shared "
               "across admissions")
-        sys.exit(1)
+        code = 1
     if st["prog_masked_emissions"]:
         print(f"FABRIC ERROR: {st['prog_masked_emissions']} tokens emitted "
               f"outside their automaton's allowed set")
-        sys.exit(1)
+        code = 1
+    return {
+        "code": code, "requests": len(requests), "served": finished,
+        "tokens": generated, "wall_s": wall, "stats": st,
+    }
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main()["code"])

@@ -65,6 +65,13 @@ def _abstract_params(cfg: ModelConfig):
     )
 
 
+def init_sharded_params(cfg: ModelConfig, mesh: Mesh, key) -> Any:
+    """Random (seeded) params created directly under their serve shardings,
+    so no device ever holds more than its own share."""
+    shard = param_shardings(_abstract_params(cfg), mesh)
+    return jax.jit(lambda k: T.init_params(k, cfg), out_shardings=shard)(key)
+
+
 def build_model(cfg: ModelConfig, mesh: Mesh, batch: int, *, strategy: str = "tp") -> Model:
     """Model with the distributed MoE apply + residual constraint bound to
     this mesh/batch."""

@@ -110,6 +110,19 @@ class Model:
         table = params["embed"] if self.cfg.tie_embeddings else params["unembed"]
         return L.unembed(x, table)
 
+    def forward(
+        self,
+        params: Params,
+        tokens: jnp.ndarray,  # (B, S)
+        frontend: Optional[jnp.ndarray] = None,  # (B, F, fd)
+    ):
+        """Non-cached full forward: (logits (B, S, V) f32, aux losses (2,))."""
+        B, S = tokens.shape
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        x = self._embed(params, tokens, frontend)
+        x, aux = self.stack_train(params, x, positions)
+        return self.logits(params, x), aux
+
     def forward_train(
         self,
         params: Params,
@@ -122,10 +135,7 @@ class Model:
         """Next-token cross-entropy over the backbone; frontend positions masked."""
         cfg = self.cfg
         B, S = tokens.shape
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        x = self._embed(params, tokens, frontend)
-        x, aux = self.stack_train(params, x, positions)
-        logits = self.logits(params, x)  # (B, S, V) f32
+        logits, aux = self.forward(params, tokens, frontend)
 
         targets = tokens[:, 1:]
         lse = jax.nn.logsumexp(logits[:, :-1], axis=-1)

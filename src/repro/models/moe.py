@@ -19,10 +19,16 @@ Two data planes execute a plan:
 * the reference plane — ``dispatch`` -> ``experts_fn`` -> ``combine`` (three
   HBM round-trips of the (E, C, d) slot tensors); always used when a custom
   ``experts_fn`` is injected.
-* the fused plane (default when ``cfg.use_pallas``) — the plan's flat SMEM
-  control words steer gather -> grouped GEMM -> scatter inside two Pallas
-  launches (:mod:`repro.kernels.moe_fused`); no (E, C, d) tensor ever hits
-  HBM.
+  Whenever kernels run (always on TPU, see
+  :func:`repro.kernels.use_kernels`) the expert compute is the grouped-GEMM
+  kernel (:mod:`repro.kernels.grouped_gemm`), differentiable through an
+  einsum backward so MoE training runs it too.
+* the fused plane (``fused=True``) — the plan's flat SMEM control words
+  steer gather -> grouped GEMM -> scatter inside two Pallas launches
+  (:mod:`repro.kernels.moe_fused`); no (E, C, d) tensor ever hits HBM.  It
+  runs in interpret mode only (CPU parity tests and
+  ``benchmarks/moe_fused.py``'s HLO counts): the TPU compiler refuses its
+  per-row gather and scatter, so on TPU ``fused=True`` raises.
 """
 from __future__ import annotations
 
@@ -48,12 +54,6 @@ Params = Dict[str, Any]
 
 # experts_fn(x_slots (E, C, d), expert_params) -> y_slots (E, C, d)
 ExpertsFn = Callable[[jnp.ndarray, Params], jnp.ndarray]
-
-# Largest f32 (T+1, d) block the fused kernels may keep whole in VMEM (gather
-# source + combine accumulator); beyond this the default data plane falls
-# back to the tiled unfused composition.  Conservative half of a 16 MB VMEM.
-_FUSED_VMEM_BYTES = 8 * 1024 * 1024
-
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
     d = cfg.d_model
@@ -114,6 +114,18 @@ def local_experts_fn(x_slots: jnp.ndarray, p: Params) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u, p["w_down"].astype(x_slots.dtype))
 
 
+def default_experts_fn(cfg: ModelConfig) -> ExpertsFn:
+    """The expert compute of the dispatch->experts->combine plane: the
+    grouped-GEMM kernel whenever kernels run, batched einsums otherwise."""
+    from repro.kernels import use_kernels
+
+    if use_kernels(cfg):
+        from repro.kernels.grouped_gemm.ops import pallas_experts_fn
+
+        return pallas_experts_fn
+    return local_experts_fn
+
+
 def moe_ffn(
     x: jnp.ndarray,  # (B, S, d)
     p: Params,
@@ -127,10 +139,9 @@ def moe_ffn(
     """Apply the MoE FFN.  If ``plan`` is provided (lookahead mode) the router
     is NOT run here — the control plane already produced the configuration.
 
-    ``fused`` selects the data plane: True forces the fused Pallas
-    gather->GEMM->scatter pipeline, False the reference
-    dispatch->experts_fn->combine composition, None (default) resolves to
-    ``cfg.use_pallas`` when no custom ``experts_fn`` is injected.
+    ``fused=True`` selects the fused Pallas gather->GEMM->scatter pipeline;
+    otherwise the dispatch->experts_fn->combine composition runs, with the
+    grouped-GEMM kernel as the default ``experts_fn`` whenever kernels run.
     """
     B, S, d = x.shape
     xf = x.reshape(B * S, d)
@@ -166,25 +177,22 @@ def moe_ffn(
                 "composition entirely; a custom experts_fn cannot apply. "
                 "Pass fused=False (or drop experts_fn)."
             )
-        if fused is not None:
-            use_fused = fused
-        else:
-            # default to the fused plane only when it fits: the fused kernels
-            # keep the (T+1, d) token block and the f32 combine accumulator
-            # whole in VMEM (see kernels/moe_fused), so very large T*d must
-            # fall back to the tiled three-stage plane
-            use_fused = (
-                cfg.use_pallas
-                and experts_fn is None
-                and (T + 1) * d * 4 <= _FUSED_VMEM_BYTES
-            )
-        if use_fused:
+        if fused:
+            from repro.kernels import on_tpu
             from repro.kernels.moe_fused.ops import fused_moe_fn
+
+            if on_tpu():
+                raise NotImplementedError(
+                    "fused=True runs only in the Pallas interpreter: the TPU "
+                    "compiler refuses the fused kernels' per-row gather and "
+                    "scatter. Serve on TPU with fused=False (dispatch -> "
+                    "grouped GEMM -> combine)."
+                )
 
             y = fused_moe_fn(xf, plan, p).astype(x.dtype)
         else:
             x_slots = dispatch(xf, plan)  # (E, C, d)
-            y_slots = (experts_fn or local_experts_fn)(x_slots, p)
+            y_slots = (experts_fn or default_experts_fn(cfg))(x_slots, p)
             y = combine(y_slots, plan).astype(x.dtype)
 
     if "shared" in p:

@@ -47,6 +47,7 @@ from repro.configs.base import ModelConfig
 from repro.core.control_plane import route_topk_decode, topk_agreement
 from repro.core.plans import DecodePlan, TreePlan
 from repro.core.quant import quantize_int8
+from repro.kernels import use_kernels
 from repro.models import layers as L
 from repro.models import mamba2, moe, rglru
 
@@ -196,8 +197,8 @@ def init_layer_cache(kind: str, cfg: ModelConfig, batch: int, max_len: int, dtyp
         S = min(max_len, window + spec_slack) if window else max_len
         hd = cfg.resolved_head_dim
         # Quantized bandwidth plane: int8 KV rows with per-token f32 scale
-        # leaves ("ks"/"vs": (B, S); paged "pks"/"pvs": (R,)) — the scales
-        # are control words riding the scalar-prefetch path, and per-TOKEN
+        # leaves ("ks"/"vs": (B, S); paged "pks"/"pvs": (R,)) — the scale
+        # rows follow their KV tile into the kernel, and per-TOKEN
         # granularity is what keeps speculative rollback / draft overwrite /
         # paged CoW token-identical to sequential decode (a per-block scale
         # would couple rows that move independently).
@@ -352,9 +353,14 @@ def apply_layer_prefill(
         ck = cache["k"].at[:, slots].set(kw.astype(cache["k"].dtype))
         cv = cache["v"].at[:, slots].set(vw.astype(cache["v"].dtype))
         new_cache["k"], new_cache["v"] = ck, cv
-        out = L.blockwise_attention(
-            q, k, v, causal=True, local_window=window, unroll=cfg.analysis_unroll
-        )
+        if use_kernels(cfg):
+            from repro.kernels.flash_attention import flash_attention
+
+            out = flash_attention(q, k, v, causal=True, window=window)
+        else:
+            out = L.blockwise_attention(
+                q, k, v, causal=True, local_window=window, unroll=cfg.analysis_unroll
+            )
         h = _res(x + jnp.einsum("bsnh,nhd->bsd", out, p["attn"]["wo"].astype(out.dtype)))
         ffn_in = L.rms_norm(h, p["ln2"])
         if kind == "moe":
@@ -684,7 +690,7 @@ def _decode_attn_prefix_spec(
         cvs = cache["vs"].at[bidx, pos].set(vsr)
     ck = cache["k"].at[bidx, pos].set(k.astype(cache["k"].dtype))
     cv = cache["v"].at[bidx, pos].set(v.astype(cache["v"].dtype))
-    if cfg.use_pallas:
+    if use_kernels(cfg):
         from repro.kernels.flash_attention import flash_decode
 
         scl = None if cks is None else jnp.stack([cks, cvs])
@@ -740,7 +746,7 @@ def _decode_attn_prefix_tree(
         cvs = cache["vs"].at[bidx, rows].set(vsr)
     ck = cache["k"].at[bidx, rows].set(k.astype(cache["k"].dtype))
     cv = cache["v"].at[bidx, rows].set(v.astype(cache["v"].dtype))
-    if cfg.use_pallas:
+    if use_kernels(cfg):
         from repro.kernels.flash_attention import flash_decode
 
         scl = None if cks is None else jnp.stack([cks, cvs])
@@ -805,7 +811,7 @@ def _decode_attn_rolling_spec(
     ck = cache["k"].at[bidx, slots].set(k.astype(cache["k"].dtype))
     cv = cache["v"].at[bidx, slots].set(v.astype(cache["v"].dtype))
     limit = min(window, W) if window else W
-    if cfg.decode_plane and cfg.use_pallas:
+    if cfg.decode_plane and use_kernels(cfg):
         from repro.kernels.flash_attention import flash_decode_window
 
         scl = None if cks is None else jnp.stack([cks, cvs])
@@ -962,7 +968,7 @@ def _decode_attn_paged_spec(
         cvs = cache["pvs"].at[rows].set(vsr, mode="drop")
     ck = cache["pk"].at[rows].set(k.astype(cache["pk"].dtype), mode="drop")
     cv = cache["pv"].at[rows].set(v.astype(cache["pv"].dtype), mode="drop")
-    if cfg.use_pallas:
+    if use_kernels(cfg):
         from repro.kernels.flash_attention import flash_decode_paged
 
         scl = None if cks is None else jnp.stack([cks, cvs])
@@ -1019,7 +1025,7 @@ def _decode_attn_paged_tree(
         cvs = cache["pvs"].at[rows].set(vsr, mode="drop")
     ck = cache["pk"].at[rows].set(k.astype(cache["pk"].dtype), mode="drop")
     cv = cache["pv"].at[rows].set(v.astype(cache["pv"].dtype), mode="drop")
-    if cfg.use_pallas:
+    if use_kernels(cfg):
         from repro.kernels.flash_attention import flash_decode_paged
 
         scl = None if cks is None else jnp.stack([cks, cvs])
@@ -1154,7 +1160,7 @@ def _decode_attn_rolling(
         nc["ks"], nc["vs"] = cks, cvs
     # validity: slot position must be within [cache_index - limit + 1, cache_index]
     limit = min(window, W) if window else W
-    if cfg.decode_plane and cfg.use_pallas and window:
+    if cfg.decode_plane and use_kernels(cfg) and window:
         # window-steered flash-decode: the rolling cache's wrap point rides
         # the scalar-prefetch path; at most W KV bytes move per head
         from repro.kernels.flash_attention import flash_decode_window
@@ -1194,8 +1200,8 @@ def _decode_attn_prefix(
     """One-token attention over the valid cache prefix [0, cache_index].
 
     The decode-plane attention path for full-attention layers (non-rolling
-    caches: slot position == absolute position).  On TPU with
-    ``cfg.use_pallas`` this is the length-steered flash-decode kernel — the
+    caches: slot position == absolute position).  Whenever kernels run
+    (always on TPU) this is the length-steered flash-decode kernel — the
     cache length rides the scalar-prefetch path and only the valid prefix's
     KV blocks are ever DMA'd (:mod:`repro.kernels.flash_attention.decode`);
     off-TPU the same prefix semantics run as masked jnp.
@@ -1210,7 +1216,7 @@ def _decode_attn_prefix(
         cvs = jax.lax.dynamic_update_slice_in_dim(cache["vs"], vsr, cache_index, axis=1)
     ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), cache_index, axis=1)
     cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), cache_index, axis=1)
-    if cfg.use_pallas:
+    if use_kernels(cfg):
         from repro.kernels.flash_attention import flash_decode
 
         scl = None if cks is None else jnp.stack([cks, cvs])

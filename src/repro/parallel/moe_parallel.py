@@ -30,12 +30,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.core.control_plane import capacity_for, combine, dispatch, route_topk
 from repro.core.plans import DecodePlan
-from repro.models.moe import _shared_experts, local_experts_fn
+from repro.models.moe import _shared_experts, default_experts_fn
 
 Params = Dict[str, Any]
 
@@ -63,20 +62,12 @@ def make_sharded_moe_apply(
     ep_axis: str = "model",
     experts_fn=None,
     capacity_factor: Optional[float] = None,
-    use_fused: Optional[bool] = None,
 ):
     """Build the distributed MoeApply (x_ffn, route_src, params) -> (y, aux(2,)).
 
     ``batch_axes`` shard the leading batch dim of x (may be empty for B=1).
-
-    ``use_fused`` (default ``cfg.use_pallas``) swaps the local data plane for
-    the fused Pallas pipeline (:mod:`repro.kernels.moe_fused`): the a2a
-    strategy keeps the slot all_to_alls (the collective layout is part of the
-    plan) but fuses the local expert compute (gate/up/SwiGLU in one launch,
-    grouped down-projection in another — no per-GEMM HBM intermediates), and
-    the psum strategy drops the local (E, C, d) dispatch/output
-    materializations entirely (plan-steered fused pipeline over the shard's
-    expert slice).  A custom ``experts_fn`` overrides both.
+    The local expert compute is ``experts_fn``, by default the grouped-GEMM
+    kernel whenever kernels run (:func:`repro.models.moe.default_experts_fn`).
     """
     E, k = cfg.num_experts, cfg.top_k
     ep = mesh.shape[ep_axis]
@@ -85,12 +76,7 @@ def make_sharded_moe_apply(
     cf = capacity_factor if capacity_factor is not None else cfg.capacity_factor
     x_spec = P(batch_axes if batch_axes else None, None, None)
     all_axes = tuple(batch_axes) + (ep_axis,)
-    fused = (cfg.use_pallas if use_fused is None else use_fused) and experts_fn is None
-    if experts_fn is None:
-        if fused:
-            from repro.kernels.moe_fused.ops import fused_experts_fn as experts_fn
-        else:
-            experts_fn = local_experts_fn
+    experts_fn = experts_fn or default_experts_fn(cfg)
 
     # ------------------------------------------------------------------
     # strategy a2a: sequence-split + all_to_all (train / prefill)
@@ -141,34 +127,19 @@ def make_sharded_moe_apply(
         midx = jax.lax.axis_index(ep_axis)
 
         plan, aux = route_topk(rs.reshape(T_loc, d), p["router"], k, C)
-        if fused:
-            # plan-steered fused pipeline over this shard's expert slice: the
-            # flat control words for experts [midx*E_loc, (midx+1)*E_loc) are a
-            # contiguous slot range, so no (E, C, d) dispatch tensor and no
-            # (E_loc, C, d) output tensor are materialized locally.
-            from repro.kernels.moe_fused.ops import fused_moe_apply
+        slots = dispatch(x.reshape(T_loc, d), plan)  # (E, C, d) replicated
+        slots_loc = jax.lax.dynamic_slice_in_dim(slots, midx * E_loc, E_loc, axis=0)
+        y_loc = experts_fn(slots_loc, p)  # (E_loc, C, d)
 
-            base = midx * (E_loc * C)
-            loc_idx = jax.lax.dynamic_slice_in_dim(plan.flat_idx, base, E_loc * C, 0)
-            loc_w = jax.lax.dynamic_slice_in_dim(plan.slot_w, base, E_loc * C, 0)
-            y = fused_moe_apply(
-                x.reshape(T_loc, d), loc_idx, loc_w,
-                p["w_gate"], p["w_up"], p["w_down"],
-            )
-        else:
-            slots = dispatch(x.reshape(T_loc, d), plan)  # (E, C, d) replicated
-            slots_loc = jax.lax.dynamic_slice_in_dim(slots, midx * E_loc, E_loc, axis=0)
-            y_loc = experts_fn(slots_loc, p)  # (E_loc, C, d)
-
-            # combine only assignments owned by this shard, sum across shards
-            base = midx * E_loc * C
-            idx = plan.combine_idx - base
-            local = (idx >= 0) & (idx < E_loc * C)
-            shifted = plan.replace_combine(
-                combine_idx=jnp.where(local, idx, -1),
-                combine_w=jnp.where(local, plan.combine_w, 0.0),
-            )
-            y = combine(y_loc, shifted)
+        # combine only assignments owned by this shard, sum across shards
+        base = midx * E_loc * C
+        idx = plan.combine_idx - base
+        local = (idx >= 0) & (idx < E_loc * C)
+        shifted = plan.replace_combine(
+            combine_idx=jnp.where(local, idx, -1),
+            combine_w=jnp.where(local, plan.combine_w, 0.0),
+        )
+        y = combine(y_loc, shifted)
         y = jax.lax.psum(y, ep_axis).astype(x.dtype)
 
         if "shared" in p:
@@ -187,12 +158,12 @@ def make_sharded_moe_apply(
         S = x_ffn.shape[1]
         body = _a2a_body if S % ep == 0 and S >= ep else _psum_body
         specs_p = _moe_param_specs(p)
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(x_spec, x_spec, specs_p),
             out_specs=(x_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(x_ffn, rs, p)
 
@@ -255,12 +226,12 @@ def make_sharded_decode_apply(
         pe = plan.expert_ids.reshape(B, S, k)
         pw = plan.weights.reshape(B, S, k)
         specs_p = _moe_param_specs(p)
-        fn = shard_map(
+        fn = jax.shard_map(
             _body,
             mesh=mesh,
             in_specs=(x_spec, x_spec, x_spec, specs_p),
             out_specs=x_spec,
-            check_rep=False,
+            check_vma=False,
         )
         return fn(x_ffn, pe, pw, p)
 

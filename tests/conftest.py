@@ -14,19 +14,14 @@ REPO = Path(__file__).resolve().parent.parent
 SRC = REPO / "src"
 
 
-# Prepended to every subprocess: expose jax.shard_map on jax releases that
-# only have the experimental spelling, so test snippets can use the current
-# public API (repro.compat.install_shard_map is idempotent).
-_COMPAT_PREAMBLE = "import repro.compat as _compat; _compat.install_shard_map()\n"
-
-
 def run_subprocess_devices(code: str, n_devices: int = 8, timeout: int = 900) -> str:
     """Run `code` in a fresh python with n host devices; returns stdout."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = str(SRC)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
-        [sys.executable, "-c", _COMPAT_PREAMBLE + code],
+        [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=timeout, env=env,
     )
     if out.returncode != 0:

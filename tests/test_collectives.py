@@ -39,9 +39,10 @@ def test_hierarchical_sync_with_compression():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         from repro.parallel.collectives import hierarchical_grad_sync
 
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         rng = np.random.default_rng(0)
         g_all = rng.standard_normal((8, 32)).astype(np.float32)
 

@@ -79,6 +79,31 @@ def test_grouped_swiglu_matches_local_experts_fn():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
 
 
+def test_moe_ffn_grad_through_grouped_gemm_kernel():
+    """Whenever kernels run (always on TPU) the MoE layer's experts are the
+    grouped-GEMM kernel, and training differentiates through it: the
+    gradient must equal the einsum data plane's."""
+    import dataclasses
+
+    from repro.configs import get_smoke_config
+    from repro.models import moe as moe_mod
+
+    cfg = dataclasses.replace(get_smoke_config("qwen3-moe-235b-a22b"), route_mode="sync")
+    p = moe_mod.init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
+
+    def loss(p, x, c, experts_fn=None):
+        y, _ = moe_mod.moe_ffn(x, p, c, experts_fn=experts_fn)
+        return jnp.sum(jnp.sin(y))
+
+    kern = dataclasses.replace(cfg, use_pallas=True)
+    assert moe_mod.default_experts_fn(kern) is not moe_mod.local_experts_fn
+    got = jax.grad(loss, argnums=(0, 1))(p, x, kern)
+    want = jax.grad(loss, argnums=(0, 1))(p, x, cfg, moe_mod.local_experts_fn)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-5)
+
+
 # ---------------------------------------------------------------------------
 # flash_attention
 # ---------------------------------------------------------------------------
@@ -164,3 +189,54 @@ def test_ssd_scan_unpadded_tail():
     yk, hk = ops.ssd_scan(x, dt, a, bm, cm, chunk=Q)
     yr, hr = ref.ssd_scan(x, dt, a, bm, cm, Q)
     np.testing.assert_allclose(np.asarray(yk), np.asarray(yr), rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# flash-decode kernels vs their f32 oracles (flash_attention/ref.py)
+# ---------------------------------------------------------------------------
+
+TREE4 = (1, 3, 5, 11)  # TreePlan.from_branching([2, 1]) ancestor words
+
+
+def _kv(rng, shape, quant):
+    from repro.core.quant import quantize_int8
+
+    x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    if not quant:
+        return x, None
+    q, s = quantize_int8(x, axis=(-2, -1))
+    return q, s[..., 0, 0]
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("kind", ["chain", "tree", "window", "paged", "paged_tree"])
+def test_flash_decode_kernels_match_oracle(kind, quant):
+    from repro.kernels.flash_attention import decode as kern
+    from repro.kernels.flash_attention import ref
+
+    rng = np.random.default_rng(len(kind) + quant)
+    B, nq, nkv, hd, S, ps = 2, 4, 2, 16, 32, 8
+    T = 4 if "tree" in kind else 2
+    q = jnp.asarray(rng.standard_normal((B, T, nq, hd)), jnp.float32)
+    (k, ks), (v, vs) = _kv(rng, (B, S, nkv, hd), quant), _kv(rng, (B, S, nkv, hd), quant)
+    scl = None if ks is None else jnp.stack([ks, vs])
+    idx = jnp.asarray([5, S - T], jnp.int32)
+    tree = dict(ancestors=jnp.asarray(TREE4, jnp.int32), base=idx) if "tree" in kind else {}
+    if kind == "window":
+        got = kern.flash_decode_window(q, k, v, idx + 40, window=12, scales=scl, bkv=8, interpret=True)
+        want = ref.flash_decode_window(q, k, v, idx + 40, window=12, scales=scl)
+    elif kind.startswith("paged"):
+        # slot b's logical page i lives at a shuffled physical page
+        mp = S // ps
+        perm = jnp.asarray(rng.permutation(B * mp), jnp.int32).reshape(B, mp)
+        pool = lambda c: jnp.zeros((B * mp * ps,) + c.shape[2:], c.dtype).at[  # noqa: E731
+            (perm[:, :, None] * ps + jnp.arange(ps)).reshape(B, S)].set(c)
+        pk, pv = pool(k), pool(v)
+        pscl = None if scl is None else jnp.stack([pool(ks), pool(vs)])
+        got = kern.flash_decode_paged(q, pk, pv, idx, perm, page_size=ps, scales=pscl,
+                                      interpret=True, **tree)
+        want = ref.flash_decode_paged(q, pk, pv, idx, perm, page_size=ps, scales=pscl, **tree)
+    else:
+        got = kern.flash_decode(q, k, v, idx, scales=scl, bkv=8, interpret=True, **tree)
+        want = ref.flash_decode(q, k, v, idx, scales=scl, **tree)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)

@@ -104,24 +104,6 @@ def test_fused_pipeline_matches_unfused_composition(T, d, E, k, f, cf):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **ULP)
 
 
-def test_fused_experts_fn_matches_local():
-    """Identity-plan fused variant is a drop-in for local_experts_fn (the
-    sharded a2a data plane's expert compute)."""
-    from repro.models.moe import local_experts_fn
-
-    rng = np.random.default_rng(3)
-    E, C, d, f = 4, 40, 64, 96
-    x_slots = jnp.asarray(rng.standard_normal((E, C, d)), jnp.float32)
-    p = {
-        "w_gate": jnp.asarray(rng.standard_normal((E, d, f)) * 0.1, jnp.float32),
-        "w_up": jnp.asarray(rng.standard_normal((E, d, f)) * 0.1, jnp.float32),
-        "w_down": jnp.asarray(rng.standard_normal((E, f, d)) * 0.1, jnp.float32),
-    }
-    got = ops.fused_experts_fn(x_slots, p)
-    want = local_experts_fn(x_slots, p)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **ULP)
-
-
 def test_moe_ffn_fused_matches_reference_data_plane():
     """moe_ffn(fused=True) == moe_ffn(fused=False) in both routed modes."""
     from repro.configs import get_smoke_config

@@ -14,9 +14,8 @@ jax.config.update("jax_platform_name", "cpu")
 
 
 def _count_flops(fn, *args):
-    from repro.compat import cost_analysis_dict
 
-    return cost_analysis_dict(jax.jit(fn).lower(*args).compile())["flops"]
+    return jax.jit(fn).lower(*args).compile().cost_analysis()["flops"]
 
 
 def test_predication_costs_more_flops_than_dispatch():
