@@ -531,16 +531,17 @@ def _flash_decode(q, k, v, cache_index, ancestors, base, scales, *, bkv, interpr
     Skv = k.shape[1]
     bkv_ = min(bkv, Skv)
     pad_kv = (-Skv) % bkv_
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    if pad_kv:
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad_kv), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad_kv), (0, 0)))
-    scl = None
-    if scales is not None:
-        scl = jnp.asarray(scales, jnp.float32)
-        if pad_kv:  # pad with ones: padded rows are masked but still multiplied
-            scl = jnp.pad(scl, ((0, 0), (0, 0), (0, pad_kv)), constant_values=1.0)
+    with jax.named_scope("attn.kv_read"):  # the cache into the kernel's layout
+        kt = jnp.swapaxes(k, 1, 2)
+        vt = jnp.swapaxes(v, 1, 2)
+        if pad_kv:
+            kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad_kv), (0, 0)))
+            vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad_kv), (0, 0)))
+        scl = None
+        if scales is not None:
+            scl = jnp.asarray(scales, jnp.float32)
+            if pad_kv:  # pad with ones: padded rows are masked but still multiplied
+                scl = jnp.pad(scl, ((0, 0), (0, 0), (0, pad_kv)), constant_values=1.0)
     lengths = _as_length_vector(cache_index, B, T)
     return flash_decode_pallas(
         q, kt, vt, lengths, anc_words=ancestors, base=base, scales=scl,
@@ -620,8 +621,9 @@ def _flash_decode_paged(q, k, v, cache_index, pages, ancestors, base, scales, *,
     R = k.shape[0]
     assert R % page_size == 0, "pool rows must be a whole number of pages"
     P = R // page_size
-    kt = jnp.swapaxes(k.reshape(P, page_size, *k.shape[1:]), 1, 2)
-    vt = jnp.swapaxes(v.reshape(P, page_size, *v.shape[1:]), 1, 2)
+    with jax.named_scope("attn.kv_read"):  # the page pool into the kernel's layout
+        kt = jnp.swapaxes(k.reshape(P, page_size, *k.shape[1:]), 1, 2)
+        vt = jnp.swapaxes(v.reshape(P, page_size, *v.shape[1:]), 1, 2)
     lengths = _as_length_vector(cache_index, B, T)
     return flash_decode_paged_pallas(
         q, kt, vt, lengths, pages.reshape(-1), anc_words=ancestors, base=base,

@@ -79,5 +79,6 @@ def grouped_gemm_pallas(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="grouped_gemm",
     )(x, w)
     return out[:, :M, :N]

@@ -78,6 +78,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.programs import compile_program, masked_argmax, program_slots
 from repro.runtime.fabric import Request, Result
 from repro.runtime.faults import RequestRejected
@@ -135,6 +136,7 @@ class ServeReplica:
         from repro.launch.steps import build_admission, build_model, build_spec_serve_step
         from repro.parallel.sharding import param_shardings
 
+        obs.install()
         self._jnp = jnp
         self.cfg, self.mesh = cfg, mesh
         self.B, self.max_len = slots, max_len
@@ -167,7 +169,6 @@ class ServeReplica:
             self.pages_shared_total = 0
             self.admissions_paged = 0
             self.admit_copy_rows = 0
-            self.trie_nodes_created = 0
         with mesh:
             bundle = build_spec_serve_step(
                 cfg, mesh, ShapeCell("d", max_len, slots, "decode"),
@@ -247,7 +248,6 @@ class ServeReplica:
         self.prefills = 0
         self.accepted_total = 0
         self.drafted_total = 0
-        self.accept_hist = np.zeros((self.T + 1,), np.int64)
         self.agreements: List[float] = []
         self.prefill_ms = 0.0
         self.last_stall = 0.0
@@ -343,9 +343,7 @@ class ServeReplica:
         for i, page in enumerate(shared):
             pager.table[b, i] = page  # probe already took our reference
         pager.ensure(b, max(L, 1), evict=evict)
-        self.trie_nodes_created += trie.insert(
-            prompt, [int(pager.table[b, i]) for i in range(L // ps)], pager
-        )
+        trie.insert(prompt, [int(pager.table[b, i]) for i in range(L // ps)], pager)
         sentinel = pager.num_pages * ps  # positive OOB: scatter drops, never wraps
         rows = np.full((self.max_len,), sentinel, np.int32)
         for pos in range(len(shared) * ps, L):
@@ -417,33 +415,46 @@ class ServeReplica:
         if self.fault_hook is not None:
             self.fault_hook(self.replica_id, self.steps + 1, "admit", (req.rid,))
         slots = free[:k]
-        t0 = time.perf_counter()
-        prompt_np = np.asarray(req.prompt, np.int32)
-        prompt = jnp.asarray(prompt_np)
-        with self.mesh:
-            one = self._one_cache_init()
-            if self.cfg.frontend:
-                fe = jnp.zeros(
-                    (1, self.cfg.frontend_tokens, self.cfg.frontend_dim), jnp.bfloat16
-                )
-                logits1, one = self._prefill(self.params, prompt[None], one, fe)
-            else:
-                logits1, one = self._prefill(self.params, prompt[None], one)
-            for i, b in enumerate(slots):
-                copied0 = self.admit_copy_rows if self.paged else 0
-                if self.paged:
-                    rows = self._bind_pages(b, prompt_np)
-                    self.cache = self._admit(self.cache, one, b, jnp.asarray(rows))
-                else:
-                    self.cache = self._admit(self.cache, one, b)
-                if i > 0:
-                    self.fork_kv_rows_copied += (
-                        self.admit_copy_rows - copied0 if self.paged
-                        else len(prompt_np)
-                    )
-        self.prefill_ms += (time.perf_counter() - t0) * 1e3
-        self.prefills += 1
-        lg1 = np.asarray(logits1[0])
+        with obs.span("serve.admit", rid=req.rid, prompt_len=len(req.prompt), slots=k) as sp:
+            with obs.span("serve.admit.prefill"):
+                prompt_np = np.asarray(req.prompt, np.int32)
+                prompt = jnp.asarray(prompt_np)
+                with self.mesh:
+                    one = self._one_cache_init()
+                    if self.cfg.frontend:
+                        fe = jnp.zeros(
+                            (1, self.cfg.frontend_tokens, self.cfg.frontend_dim), jnp.bfloat16
+                        )
+                        logits1, one = self._prefill(self.params, prompt[None], one, fe)
+                    else:
+                        logits1, one = self._prefill(self.params, prompt[None], one)
+            with obs.span("serve.admit.write"), self.mesh:
+                for i, b in enumerate(slots):
+                    copied0 = self.admit_copy_rows if self.paged else 0
+                    if self.paged:
+                        rows = self._bind_pages(b, prompt_np)
+                        self.cache = self._admit(self.cache, one, b, jnp.asarray(rows))
+                    else:
+                        self.cache = self._admit(self.cache, one, b)
+                    if i > 0:
+                        self.fork_kv_rows_copied += (
+                            self.admit_copy_rows - copied0 if self.paged
+                            else len(prompt_np)
+                        )
+            with obs.span("serve.admit.sync") as sync:
+                lg1 = np.asarray(logits1[0])
+            # timed to the device's finish: the host read above is the wait
+            self.prefill_ms += (sync.end - sp.start) * 1e3
+            self.prefills += 1
+            with obs.span("serve.admit.host"):
+                self._first_tokens(req, prog, slots, lg1, prompt)
+        return slots[0]
+
+    def _first_tokens(self, req: Request, prog, slots: List[int], lg1: np.ndarray,
+                      prompt) -> None:
+        """Slot state of an admitted request: each branch's first token from
+        the prefill logits ``lg1``, its program state and its drafter."""
+        k = len(slots)
         if prog is not None:
             auto = prog.automaton
             mask = auto.mask(auto.start)
@@ -492,7 +503,6 @@ class ServeReplica:
                 self.prog_state[b] = -1
             if self._drafter is not None:
                 self._drafter.admit(b, prompt)
-        return slots[0]
 
     # ------------------------------------------------------------------
     def step(self) -> List[Result]:
@@ -501,9 +511,33 @@ class ServeReplica:
         completed this step (their full buffered token streams)."""
         if not self.active.any():
             return []
-        jnp = self._jnp
-        from repro.launch.speculative import greedy_accept_tree
+        with obs.span("serve.step", step=self.steps + 1, active=int(self.active.sum())):
+            with obs.span("serve.step.prepare"):
+                toks, args = self._prepare_launch()
+            with obs.span("serve.step.dispatch"):
+                with self.mesh:
+                    out = self._decode(self.params, self.cache, *args)
+                if self.telemetry:
+                    logits, self.cache, metrics = out
+                else:
+                    logits, self.cache = out
+                self.launches += 1
+            with obs.span("serve.step.sync"):
+                if self.telemetry:
+                    self.agreements.append(float(metrics["plan_agreement"]))
+                # np.array (not asarray): programmed slots overwrite rows with
+                # the masked argmax, and jax buffers view as read-only
+                y = np.array(self._jnp.argmax(logits, -1))  # (B, T) verified tokens
+            with obs.span("serve.step.verify"):
+                acc_n, prog_done = self._verify(toks, logits, y)
+            with obs.span("serve.step.retire"):
+                return self._retire_finished(acc_n, prog_done)
 
+    def _prepare_launch(self):
+        """Fault hook, draft, page growth and control words: returns the
+        launch's tokens (B, T) and the decode step's arguments after the
+        params and the cache."""
+        jnp = self._jnp
         step_no = self.steps + 1
         self.last_stall = 0.0
         if self.fault_hook is not None:
@@ -561,7 +595,6 @@ class ServeReplica:
                         toks[b], self._propose_tree, g[0], g[1], self.history[b]
                     )
 
-        # ---- one speculative launch over the ragged pool -------------------
         if self.paged:
             # grow each active slot's block table to cover this launch's
             # writes BEFORE prefetch; the table is the launch's control word
@@ -570,6 +603,10 @@ class ServeReplica:
                 if self.active[b]:
                     self.pager.ensure(b, int(self.lengths[b]) + T, evict=evict)
         with self.mesh:
+            args = [jnp.asarray(toks), jnp.asarray(self.lengths),
+                    jnp.asarray(self.prev_accept)]
+            if self.paged:
+                args.append(jnp.asarray(self.pager.table))
             if self.paged and self._branchy:
                 # previous step's accepted tree path rides in as (dst, src)
                 # row-move maps, applied at the top of this launch (fused
@@ -579,33 +616,16 @@ class ServeReplica:
                     if self._pending_commit is not None
                     else (np.full((B, T), -1, np.int32),) * 2
                 )
-                out = self._decode(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(self.lengths), jnp.asarray(self.prev_accept),
-                    jnp.asarray(self.pager.table), jnp.asarray(dst),
-                    jnp.asarray(src),
-                )
-            elif self.paged:
-                out = self._decode(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(self.lengths), jnp.asarray(self.prev_accept),
-                    jnp.asarray(self.pager.table),
-                )
-            else:
-                out = self._decode(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(self.lengths), jnp.asarray(self.prev_accept),
-                )
-        if self.telemetry:
-            logits, self.cache, metrics = out
-            self.agreements.append(float(metrics["plan_agreement"]))
-        else:
-            logits, self.cache = out
-        self.launches += 1
-        # np.array (not asarray): programmed slots overwrite rows with the
-        # masked argmax, and jax buffers view as read-only
-        y = np.array(jnp.argmax(logits, -1))  # (B, T) verified tokens
+                args += [jnp.asarray(dst), jnp.asarray(src)]
+        return toks, args
 
+    def _verify(self, toks: np.ndarray, logits, y: np.ndarray):
+        """Constraint masks, greedy verify/rollback and tree commit; returns
+        each slot's accepted count and whether its program finished."""
+        jnp = self._jnp
+        from repro.launch.speculative import greedy_accept_tree
+
+        T, B = self.T, self.B
         # ---- constraint masks inside the verify step -----------------------
         # per draft node, the automaton state implied by the node's root-path
         # draft tokens selects the allowed set its emission is masked with;
@@ -683,7 +703,6 @@ class ServeReplica:
                 self.prog_tokens += a
             self.accepted_total += a
             self.drafted_total += T
-            self.accept_hist[a] += 1
             acc_n[b] = a
             self.gen_left[b] -= a
             self.last_tok[b] = accepted[-1]
@@ -704,6 +723,11 @@ class ServeReplica:
                     self.cache = self._commit(
                         self.cache, jnp.asarray(self.lengths), jnp.asarray(path_pad)
                     )
+        return acc_n, prog_done
+
+    def _retire_finished(self, acc_n: np.ndarray, prog_done: np.ndarray) -> List[Result]:
+        """Advance lengths, retire finished slots, resolve fork groups."""
+        T, B = self.T, self.B
         done: List[Result] = []
         for b in range(B):
             if not self.active[b]:

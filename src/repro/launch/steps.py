@@ -437,9 +437,11 @@ def build_admission(
     c1_shard = cache_shardings(c1_abs, 1, mesh)
     lg1_shard = NamedSharding(mesh, batch_spec(1, mesh, extra_dims=1))
     prefill = jax.jit(pf_model.prefill, out_shardings=(lg1_shard, c1_shard))
-    one_cache_init = jax.jit(
-        lambda: T.init_cache(pf_cfg, 1, max_len), out_shardings=c1_shard
-    )
+
+    def one_cache_init():
+        return T.init_cache(pf_cfg, 1, max_len)
+
+    one_cache_init = jax.jit(one_cache_init, out_shardings=c1_shard)
     admit_fn = (
         serve_model.write_cache_slot_paged if cfg.paged else serve_model.write_cache_slot
     )

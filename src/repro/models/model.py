@@ -164,7 +164,8 @@ class Model:
         cfg = self.cfg
         B, S = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        x = self._embed(params, tokens, frontend)
+        with jax.named_scope("embed"):
+            x = self._embed(params, tokens, frontend)
         pat, n_sb, n_rest = self._pattern()
         route_src = x
 
@@ -182,17 +183,19 @@ class Model:
             return (h, rs), new_c
 
         new_cache: Params = {"scan": {}, "rest": []}
-        if n_sb:
-            (x, route_src), new_scan = jax.lax.scan(
-                sb_fn, (x, route_src), (params["blocks"]["scan"], cache["scan"])
-            )
-            new_cache["scan"] = new_scan
-        kinds = cfg.layer_kinds
-        for j, (p, c) in enumerate(zip(params["blocks"]["rest"], cache["rest"])):
-            kind = kinds[n_sb * len(pat) + j]
-            x, route_src, nc, _ = T.apply_layer_prefill(x, route_src, p, c, kind, cfg, positions, self.moe_apply)
-            new_cache["rest"].append(nc)
-        last = self.logits(params, x[:, -1:, :])[:, 0]  # (B, V)
+        with jax.named_scope("layers"):
+            if n_sb:
+                (x, route_src), new_scan = jax.lax.scan(
+                    sb_fn, (x, route_src), (params["blocks"]["scan"], cache["scan"])
+                )
+                new_cache["scan"] = new_scan
+            kinds = cfg.layer_kinds
+            for j, (p, c) in enumerate(zip(params["blocks"]["rest"], cache["rest"])):
+                kind = kinds[n_sb * len(pat) + j]
+                x, route_src, nc, _ = T.apply_layer_prefill(x, route_src, p, c, kind, cfg, positions, self.moe_apply)
+                new_cache["rest"].append(nc)
+        with jax.named_scope("head"):
+            last = self.logits(params, x[:, -1:, :])[:, 0]  # (B, V)
         return last, new_cache
 
     # ------------------------------------------------------------------
@@ -299,8 +302,9 @@ class Model:
         if prev_accept is None:
             prev_accept = jnp.zeros((B,), jnp.int32)
         lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
-        x = L.embed(tokens, params["embed"], jnp.dtype(cfg.dtype))
-        x = x * jnp.sqrt(jnp.float32(cfg.d_model)).astype(x.dtype)
+        with jax.named_scope("embed"):
+            x = L.embed(tokens, params["embed"], jnp.dtype(cfg.dtype))
+            x = x * jnp.sqrt(jnp.float32(cfg.d_model)).astype(x.dtype)
         pat, n_sb, n_rest = self._pattern()
         route_src = x
         agree_sum = jnp.float32(0.0)
@@ -322,22 +326,25 @@ class Model:
             return (h, rs, agg), new_c
 
         new_cache: Params = {"scan": {}, "rest": []}
-        if n_sb:
-            (x, route_src, agree_sum), new_scan = jax.lax.scan(
-                sb_fn, (x, route_src, agree_sum), (params["blocks"]["scan"], cache["scan"])
-            )
-            new_cache["scan"] = new_scan
-        kinds = cfg.layer_kinds
-        for j, (p, c) in enumerate(zip(params["blocks"]["rest"], cache["rest"])):
-            kind = kinds[n_sb * len(pat) + j]
-            x, route_src, nc, a = T.apply_layer_decode_spec(
-                x, route_src, p, c, kind, cfg, lengths, prev_accept,
-                self.moe_apply, decode_apply=self.decode_moe_apply,
-                telemetry=telemetry, tree=tree, pages=pages, commit=commit,
-            )
-            new_cache["rest"].append(nc)
-            agree_sum = agree_sum + a
-        logits = self.logits(params, x)  # (B, T, V)
+        # "layers" names the scan's own slicing and restacking of the cache
+        with jax.named_scope("layers"):
+            if n_sb:
+                (x, route_src, agree_sum), new_scan = jax.lax.scan(
+                    sb_fn, (x, route_src, agree_sum), (params["blocks"]["scan"], cache["scan"])
+                )
+                new_cache["scan"] = new_scan
+            kinds = cfg.layer_kinds
+            for j, (p, c) in enumerate(zip(params["blocks"]["rest"], cache["rest"])):
+                kind = kinds[n_sb * len(pat) + j]
+                x, route_src, nc, a = T.apply_layer_decode_spec(
+                    x, route_src, p, c, kind, cfg, lengths, prev_accept,
+                    self.moe_apply, decode_apply=self.decode_moe_apply,
+                    telemetry=telemetry, tree=tree, pages=pages, commit=commit,
+                )
+                new_cache["rest"].append(nc)
+                agree_sum = agree_sum + a
+        with jax.named_scope("head"):
+            logits = self.logits(params, x)  # (B, T, V)
         if telemetry:
             return logits, new_cache, {"plan_agreement": agree_sum / n_moe}
         return logits, new_cache

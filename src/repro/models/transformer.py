@@ -332,62 +332,67 @@ def apply_layer_prefill(
                 "through the admission path (B=1 contiguous prefill + page "
                 "scatter) — build the prefill model with paged=False"
             )
-        xn = L.rms_norm(x, p["ln1"])
-        q, k, v = L._qkv(xn, p["attn"], cfg, positions)
+        with jax.named_scope("attn.qkv"):
+            xn = L.rms_norm(x, p["ln1"])
+            q, k, v = L._qkv(xn, p["attn"], cfg, positions)
         S = x.shape[1]
         W = cache["k"].shape[1]
         # write the last min(W, S) positions at rolling slots (pos % W), so
         # decode's rolling-window addressing continues seamlessly
         take = min(W, S)
         slots = jnp.arange(S - take, S, dtype=jnp.int32) % W
-        kw, vw = k[:, -take:], v[:, -take:]
-        new_cache = {}
-        if "ks" in cache:
-            # quantize at admission: the attention math above stays full
-            # precision (prefill logits are exact); only the CACHE rows are
-            # int8 + per-token scale control words, so every decode step —
-            # speculative or sequential — reads the same quantized prefix
-            kw, vw, ksr, vsr = _quant_kv_rows(kw, vw)
-            new_cache["ks"] = cache["ks"].at[:, slots].set(ksr)
-            new_cache["vs"] = cache["vs"].at[:, slots].set(vsr)
-        ck = cache["k"].at[:, slots].set(kw.astype(cache["k"].dtype))
-        cv = cache["v"].at[:, slots].set(vw.astype(cache["v"].dtype))
-        new_cache["k"], new_cache["v"] = ck, cv
-        if use_kernels(cfg):
-            from repro.kernels.flash_attention import flash_attention
+        with jax.named_scope("attn.kv_write"):
+            kw, vw = k[:, -take:], v[:, -take:]
+            new_cache = {}
+            if "ks" in cache:
+                # quantize at admission: the attention math above stays full
+                # precision (prefill logits are exact); only the CACHE rows are
+                # int8 + per-token scale control words, so every decode step —
+                # speculative or sequential — reads the same quantized prefix
+                kw, vw, ksr, vsr = _quant_kv_rows(kw, vw)
+                new_cache["ks"] = cache["ks"].at[:, slots].set(ksr)
+                new_cache["vs"] = cache["vs"].at[:, slots].set(vsr)
+            ck = cache["k"].at[:, slots].set(kw.astype(cache["k"].dtype))
+            cv = cache["v"].at[:, slots].set(vw.astype(cache["v"].dtype))
+            new_cache["k"], new_cache["v"] = ck, cv
+        with jax.named_scope("attn.kernel"):
+            if use_kernels(cfg):
+                from repro.kernels.flash_attention import flash_attention
 
-            out = flash_attention(q, k, v, causal=True, window=window)
-        else:
-            out = L.blockwise_attention(
-                q, k, v, causal=True, local_window=window, unroll=cfg.analysis_unroll
-            )
-        h = _res(x + jnp.einsum("bsnh,nhd->bsd", out, p["attn"]["wo"].astype(out.dtype)))
-        ffn_in = L.rms_norm(h, p["ln2"])
-        if kind == "moe":
-            if cfg.decode_plane:
-                # seed the first decode step's plan from the prompt's last
-                # control-plane source (the same route_src stream decode
-                # consumes one step later) — plan rides the cache from here on
-                src = (route_src if route_src is not None else h)[:, -1, :]
-                seed = route_topk_decode(src, p["moe"]["router"], cfg.top_k)
-                if cfg.spec_tokens > 1:
-                    # plan-vector carry: every draft position of the first
-                    # launch starts from the same prefill-seeded plan
-                    B_, Tp, k_ = x.shape[0], cfg.spec_tokens, cfg.top_k
-                    new_cache["plan_e"] = jnp.broadcast_to(
-                        seed.expert_ids[:, None], (B_, Tp, k_)
-                    ).astype(jnp.int32)
-                    new_cache["plan_w"] = jnp.broadcast_to(
-                        seed.weights[:, None], (B_, Tp, k_)
-                    ).astype(jnp.float32)
-                else:
-                    new_cache["plan_e"] = seed.expert_ids
-                    new_cache["plan_w"] = seed.weights
-            y, aux = moe_apply(ffn_in, route_src, p["moe"])
-            route_src = h
-        else:
-            y = L.swiglu(ffn_in, p["ffn"])
-        x = _res(h + y)
+                out = flash_attention(q, k, v, causal=True, window=window)
+            else:
+                out = L.blockwise_attention(
+                    q, k, v, causal=True, local_window=window, unroll=cfg.analysis_unroll
+                )
+        with jax.named_scope("attn.out"):
+            h = _res(x + jnp.einsum("bsnh,nhd->bsd", out, p["attn"]["wo"].astype(out.dtype)))
+        with jax.named_scope("ffn"):
+            ffn_in = L.rms_norm(h, p["ln2"])
+            if kind == "moe":
+                if cfg.decode_plane:
+                    # seed the first decode step's plan from the prompt's last
+                    # control-plane source (the same route_src stream decode
+                    # consumes one step later) — plan rides the cache from here on
+                    src = (route_src if route_src is not None else h)[:, -1, :]
+                    seed = route_topk_decode(src, p["moe"]["router"], cfg.top_k)
+                    if cfg.spec_tokens > 1:
+                        # plan-vector carry: every draft position of the first
+                        # launch starts from the same prefill-seeded plan
+                        B_, Tp, k_ = x.shape[0], cfg.spec_tokens, cfg.top_k
+                        new_cache["plan_e"] = jnp.broadcast_to(
+                            seed.expert_ids[:, None], (B_, Tp, k_)
+                        ).astype(jnp.int32)
+                        new_cache["plan_w"] = jnp.broadcast_to(
+                            seed.weights[:, None], (B_, Tp, k_)
+                        ).astype(jnp.float32)
+                    else:
+                        new_cache["plan_e"] = seed.expert_ids
+                        new_cache["plan_w"] = seed.weights
+                y, aux = moe_apply(ffn_in, route_src, p["moe"])
+                route_src = h
+            else:
+                y = L.swiglu(ffn_in, p["ffn"])
+            x = _res(h + y)
     elif kind == "rec":
         r, new_cache = rglru.rec_block_prefill(L.rms_norm(x, p["ln1"]), p["rec"], cfg)
         h = _res(x + r)
@@ -543,7 +548,8 @@ def apply_layer_decode_spec(
                 "contiguous path serves local-attention archs with chain "
                 "drafts only"
             )
-        xn = L.rms_norm(x, p["ln1"])
+        with jax.named_scope("attn.qkv"):
+            xn = L.rms_norm(x, p["ln1"])
         if tree is not None and window:
             a, new_cache = _decode_attn_rolling_tree(
                 xn, p["attn"], cfg, cache, lengths, window, tree
@@ -560,62 +566,64 @@ def apply_layer_decode_spec(
             a, new_cache = _decode_attn_paged_spec(xn, p["attn"], cfg, cache, lengths, pages)
         else:
             a, new_cache = _decode_attn_prefix_spec(xn, p["attn"], cfg, cache, lengths)
-        h = _res(x + a)
-        ffn_in = L.rms_norm(h, p["ln2"])
-        if kind == "moe":
-            if cfg.decode_plane:
-                src_seq = route_src if route_src is not None else h  # (B, T, d)
-                k_ = cfg.top_k
-                # one router launch covers draft routing AND next-launch plans
-                nxt = route_topk_decode(
-                    src_seq.reshape(B * T, d), p["moe"]["router"], k_
-                )
-                all_e = nxt.expert_ids.reshape(B, T, k_)
-                all_w = nxt.weights.reshape(B, T, k_)
-                cached_e, cached_w = cache["plan_e"], cache["plan_w"]
-                if cached_e.ndim == 3:
-                    sel = prev_accept[:, None, None]
-                    first_e = jnp.take_along_axis(cached_e, sel, axis=1)[:, 0]
-                    first_w = jnp.take_along_axis(cached_w, sel, axis=1)[:, 0]
-                else:  # spec_tokens == 1 cache: single temporal plan row
-                    first_e, first_w = cached_e, cached_w
-                if tree is not None:
-                    # plan-row selection follows the accepted ancestor chain:
-                    # node t consumes the plan routed from its parent's route
-                    # source (the sequential predecessor on its root path),
-                    # not row t-1 (a chain tree gathers rows 0..T-2: bitwise
-                    # the linear concatenate-shift)
-                    par = jnp.asarray(
-                        [max(pp, 0) for pp in tree.parents], jnp.int32
+        with jax.named_scope("attn.out"):
+            h = _res(x + a)
+        with jax.named_scope("ffn"):
+            ffn_in = L.rms_norm(h, p["ln2"])
+            if kind == "moe":
+                if cfg.decode_plane:
+                    src_seq = route_src if route_src is not None else h  # (B, T, d)
+                    k_ = cfg.top_k
+                    # one router launch covers draft routing AND next-launch plans
+                    nxt = route_topk_decode(
+                        src_seq.reshape(B * T, d), p["moe"]["router"], k_
                     )
-                    sel_p = jnp.broadcast_to(par[None, :, None], (B, T, k_))
-                    prev_e = jnp.take_along_axis(all_e, sel_p, axis=1)
-                    prev_w = jnp.take_along_axis(all_w, sel_p, axis=1)
-                    cons_e = jnp.concatenate([first_e[:, None], prev_e[:, 1:]], axis=1)
-                    cons_w = jnp.concatenate([first_w[:, None], prev_w[:, 1:]], axis=1)
+                    all_e = nxt.expert_ids.reshape(B, T, k_)
+                    all_w = nxt.weights.reshape(B, T, k_)
+                    cached_e, cached_w = cache["plan_e"], cache["plan_w"]
+                    if cached_e.ndim == 3:
+                        sel = prev_accept[:, None, None]
+                        first_e = jnp.take_along_axis(cached_e, sel, axis=1)[:, 0]
+                        first_w = jnp.take_along_axis(cached_w, sel, axis=1)[:, 0]
+                    else:  # spec_tokens == 1 cache: single temporal plan row
+                        first_e, first_w = cached_e, cached_w
+                    if tree is not None:
+                        # plan-row selection follows the accepted ancestor chain:
+                        # node t consumes the plan routed from its parent's route
+                        # source (the sequential predecessor on its root path),
+                        # not row t-1 (a chain tree gathers rows 0..T-2: bitwise
+                        # the linear concatenate-shift)
+                        par = jnp.asarray(
+                            [max(pp, 0) for pp in tree.parents], jnp.int32
+                        )
+                        sel_p = jnp.broadcast_to(par[None, :, None], (B, T, k_))
+                        prev_e = jnp.take_along_axis(all_e, sel_p, axis=1)
+                        prev_w = jnp.take_along_axis(all_w, sel_p, axis=1)
+                        cons_e = jnp.concatenate([first_e[:, None], prev_e[:, 1:]], axis=1)
+                        cons_w = jnp.concatenate([first_w[:, None], prev_w[:, 1:]], axis=1)
+                    else:
+                        cons_e = jnp.concatenate([first_e[:, None], all_e[:, : T - 1]], axis=1)
+                        cons_w = jnp.concatenate([first_w[:, None], all_w[:, : T - 1]], axis=1)
+                    plan = DecodePlan(cons_e, cons_w)  # (B, T, k): one row per draft
+                    y = (decode_apply or moe.moe_decode_ffn)(ffn_in, plan, p["moe"])
+                    if cached_e.ndim == 3:
+                        new_cache["plan_e"] = all_e
+                        new_cache["plan_w"] = all_w
+                    else:
+                        new_cache["plan_e"] = all_e[:, -1]
+                        new_cache["plan_w"] = all_w[:, -1]
+                    if telemetry:
+                        # stale (consumed, position t-1 source) vs fresh (same
+                        # position source) — the decode-plane lookahead bet
+                        agree = topk_agreement(
+                            cons_e.reshape(B * T, k_), all_e.reshape(B * T, k_)
+                        )
                 else:
-                    cons_e = jnp.concatenate([first_e[:, None], all_e[:, : T - 1]], axis=1)
-                    cons_w = jnp.concatenate([first_w[:, None], all_w[:, : T - 1]], axis=1)
-                plan = DecodePlan(cons_e, cons_w)  # (B, T, k): one row per draft
-                y = (decode_apply or moe.moe_decode_ffn)(ffn_in, plan, p["moe"])
-                if cached_e.ndim == 3:
-                    new_cache["plan_e"] = all_e
-                    new_cache["plan_w"] = all_w
-                else:
-                    new_cache["plan_e"] = all_e[:, -1]
-                    new_cache["plan_w"] = all_w[:, -1]
-                if telemetry:
-                    # stale (consumed, position t-1 source) vs fresh (same
-                    # position source) — the decode-plane lookahead bet
-                    agree = topk_agreement(
-                        cons_e.reshape(B * T, k_), all_e.reshape(B * T, k_)
-                    )
+                    y, _ = moe_apply(ffn_in, route_src, p["moe"])
+                route_src = h
             else:
-                y, _ = moe_apply(ffn_in, route_src, p["moe"])
-            route_src = h
-        else:
-            y = L.swiglu(ffn_in, p["ffn"])
-        x = _res(h + y)
+                y = L.swiglu(ffn_in, p["ffn"])
+            x = _res(h + y)
     elif kind in ("rec", "ssm"):
         # stateful recurrences advance one token per launch: supported at
         # spec width 1 (ragged continuous batching without drafts)
@@ -680,35 +688,39 @@ def _decode_attn_prefix_spec(
     sees draft tokens < t (already written to the cache) and nothing after.
     """
     B, T, _ = xn.shape
-    pos = _spec_positions(lengths, T)
-    q, k, v = L._qkv(xn, p, cfg, pos)
-    bidx = jnp.arange(B)[:, None]
-    cks = cvs = None
-    if "ks" in cache:
-        k, v, ksr, vsr = _quant_kv_rows(k, v)
-        cks = cache["ks"].at[bidx, pos].set(ksr)
-        cvs = cache["vs"].at[bidx, pos].set(vsr)
-    ck = cache["k"].at[bidx, pos].set(k.astype(cache["k"].dtype))
-    cv = cache["v"].at[bidx, pos].set(v.astype(cache["v"].dtype))
-    if use_kernels(cfg):
-        from repro.kernels.flash_attention import flash_decode
+    with jax.named_scope("attn.qkv"):
+        pos = _spec_positions(lengths, T)
+        q, k, v = L._qkv(xn, p, cfg, pos)
+    with jax.named_scope("attn.kv_write"):
+        bidx = jnp.arange(B)[:, None]
+        cks = cvs = None
+        if "ks" in cache:
+            k, v, ksr, vsr = _quant_kv_rows(k, v)
+            cks = cache["ks"].at[bidx, pos].set(ksr)
+            cvs = cache["vs"].at[bidx, pos].set(vsr)
+        ck = cache["k"].at[bidx, pos].set(k.astype(cache["k"].dtype))
+        cv = cache["v"].at[bidx, pos].set(v.astype(cache["v"].dtype))
+    with jax.named_scope("attn.kernel"):
+        if use_kernels(cfg):
+            from repro.kernels.flash_attention import flash_decode
 
-        scl = None if cks is None else jnp.stack([cks, cvs])
-        out = flash_decode(q, ck, cv, pos, scales=scl)  # (B, T, nq, hd)
-    else:
-        S = ck.shape[1]
-        hd, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
-        groups = cfg.num_heads // nkv
-        ckf, cvf = _deq(ck, cks), _deq(cv, cvs)
-        valid = jnp.arange(S)[None, None, :] <= pos[:, :, None]  # (B, T, S)
-        scale = 1.0 / math.sqrt(hd)
-        qg = q.reshape(B, T, nkv, groups, hd)
-        s = jnp.einsum("btngh,bsnh->bngts", qg.astype(jnp.float32), ckf.astype(jnp.float32)) * scale
-        s = jnp.where(valid[:, None, None, :, :], s, L.NEG_INF)
-        w = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bngts,bsnh->btngh", w, cvf.astype(jnp.float32))
-        out = out.reshape(B, T, cfg.num_heads, hd).astype(xn.dtype)
-    y = jnp.einsum("btnh,nhd->btd", out, p["wo"].astype(out.dtype))
+            scl = None if cks is None else jnp.stack([cks, cvs])
+            out = flash_decode(q, ck, cv, pos, scales=scl)  # (B, T, nq, hd)
+        else:
+            S = ck.shape[1]
+            hd, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
+            groups = cfg.num_heads // nkv
+            ckf, cvf = _deq(ck, cks), _deq(cv, cvs)
+            valid = jnp.arange(S)[None, None, :] <= pos[:, :, None]  # (B, T, S)
+            scale = 1.0 / math.sqrt(hd)
+            qg = q.reshape(B, T, nkv, groups, hd)
+            s = jnp.einsum("btngh,bsnh->bngts", qg.astype(jnp.float32), ckf.astype(jnp.float32)) * scale
+            s = jnp.where(valid[:, None, None, :, :], s, L.NEG_INF)
+            w = jax.nn.softmax(s, axis=-1)
+            out = jnp.einsum("bngts,bsnh->btngh", w, cvf.astype(jnp.float32))
+            out = out.reshape(B, T, cfg.num_heads, hd).astype(xn.dtype)
+    with jax.named_scope("attn.out"):
+        y = jnp.einsum("btnh,nhd->btd", out, p["wo"].astype(out.dtype))
     nc = {"k": ck, "v": cv}
     if cks is not None:
         nc["ks"], nc["vs"] = cks, cvs
@@ -958,39 +970,43 @@ def _decode_attn_paged_spec(
     B, T, _ = xn.shape
     ps = cfg.page_size
     R = cache["pk"].shape[0]
-    pos = _spec_positions(lengths, T)
-    q, k, v = L._qkv(xn, p, cfg, pos)
-    rows = _paged_rows(pages, pos, ps, R)
-    cks = cvs = None
-    if "pks" in cache:
-        k, v, ksr, vsr = _quant_kv_rows(k, v)
-        cks = cache["pks"].at[rows].set(ksr, mode="drop")
-        cvs = cache["pvs"].at[rows].set(vsr, mode="drop")
-    ck = cache["pk"].at[rows].set(k.astype(cache["pk"].dtype), mode="drop")
-    cv = cache["pv"].at[rows].set(v.astype(cache["pv"].dtype), mode="drop")
-    if use_kernels(cfg):
-        from repro.kernels.flash_attention import flash_decode_paged
+    with jax.named_scope("attn.qkv"):
+        pos = _spec_positions(lengths, T)
+        q, k, v = L._qkv(xn, p, cfg, pos)
+    with jax.named_scope("attn.kv_write"):
+        rows = _paged_rows(pages, pos, ps, R)
+        cks = cvs = None
+        if "pks" in cache:
+            k, v, ksr, vsr = _quant_kv_rows(k, v)
+            cks = cache["pks"].at[rows].set(ksr, mode="drop")
+            cvs = cache["pvs"].at[rows].set(vsr, mode="drop")
+        ck = cache["pk"].at[rows].set(k.astype(cache["pk"].dtype), mode="drop")
+        cv = cache["pv"].at[rows].set(v.astype(cache["pv"].dtype), mode="drop")
+    with jax.named_scope("attn.kernel"):
+        if use_kernels(cfg):
+            from repro.kernels.flash_attention import flash_decode_paged
 
-        scl = None if cks is None else jnp.stack([cks, cvs])
-        out = flash_decode_paged(q, ck, cv, pos, pages, page_size=ps, scales=scl)
-    else:
-        Smax = pages.shape[1] * ps
-        hd, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
-        groups = cfg.num_heads // nkv
-        vk = _paged_view(_deq(ck, cks), pages, ps)  # (B, Smax, nkv, hd)
-        vv = _paged_view(_deq(cv, cvs), pages, ps)
-        mapped = jnp.repeat(pages >= 0, ps, axis=1)  # (B, Smax)
-        valid = mapped[:, None, :] & (
-            jnp.arange(Smax)[None, None, :] <= pos[:, :, None]
-        )  # (B, T, Smax)
-        scale = 1.0 / math.sqrt(hd)
-        qg = q.reshape(B, T, nkv, groups, hd)
-        s = jnp.einsum("btngh,bsnh->bngts", qg.astype(jnp.float32), vk.astype(jnp.float32)) * scale
-        s = jnp.where(valid[:, None, None, :, :], s, L.NEG_INF)
-        w = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bngts,bsnh->btngh", w, vv.astype(jnp.float32))
-        out = out.reshape(B, T, cfg.num_heads, hd).astype(xn.dtype)
-    y = jnp.einsum("btnh,nhd->btd", out, p["wo"].astype(out.dtype))
+            scl = None if cks is None else jnp.stack([cks, cvs])
+            out = flash_decode_paged(q, ck, cv, pos, pages, page_size=ps, scales=scl)
+        else:
+            Smax = pages.shape[1] * ps
+            hd, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
+            groups = cfg.num_heads // nkv
+            vk = _paged_view(_deq(ck, cks), pages, ps)  # (B, Smax, nkv, hd)
+            vv = _paged_view(_deq(cv, cvs), pages, ps)
+            mapped = jnp.repeat(pages >= 0, ps, axis=1)  # (B, Smax)
+            valid = mapped[:, None, :] & (
+                jnp.arange(Smax)[None, None, :] <= pos[:, :, None]
+            )  # (B, T, Smax)
+            scale = 1.0 / math.sqrt(hd)
+            qg = q.reshape(B, T, nkv, groups, hd)
+            s = jnp.einsum("btngh,bsnh->bngts", qg.astype(jnp.float32), vk.astype(jnp.float32)) * scale
+            s = jnp.where(valid[:, None, None, :, :], s, L.NEG_INF)
+            w = jax.nn.softmax(s, axis=-1)
+            out = jnp.einsum("bngts,bsnh->btngh", w, vv.astype(jnp.float32))
+            out = out.reshape(B, T, cfg.num_heads, hd).astype(xn.dtype)
+    with jax.named_scope("attn.out"):
+        y = jnp.einsum("btnh,nhd->btd", out, p["wo"].astype(out.dtype))
     nc = {"pk": ck, "pv": cv}
     if cks is not None:
         nc["pks"], nc["pvs"] = cks, cvs
