@@ -7,13 +7,33 @@ PR 2 carried ONE scalar length (one token, one sequence); here the control
 word is a **vector** of per-token lengths, the same promotion TileLoom makes
 from whole-loop schedules to tile-granular plans:
 
-* grid (B, T, nkv, Skv/bkv): T draft/speculative tokens attend in ONE launch
-  instead of T, and one block carries every query head of one KV head (a
-  (group, hd) tile: whole array dims, so the TPU tiling rule holds, and
-  each KV tile is fetched once per group).  The KV BlockSpec index_maps clamp the block index per
-  (b, t) against the prefetched length vector, so no DMA is ever issued for
-  cache tail blocks beyond that token's prefix — the length vector literally
-  steers which HBM blocks move, per token.
+* grid (B, T, cdiv(S, bkv)) over the cache as the model stores it,
+  (B, S, nkv, hd): T draft/speculative tokens attend in ONE launch instead
+  of T, and one (1, bkv, nkv, hd) block holds bkv cache rows of every KV
+  head.  Its trailing (nkv, hd) dims are whole array dims, so the TPU tiling
+  rule holds for any head count and width, and the block is made of the
+  stored layout's own tiles, so nothing is transposed or padded before the
+  launch and each KV tile moves once per (b, t, block).  The KV BlockSpec
+  index_maps clamp the block index per (b, t) against the prefetched length
+  vector, so no DMA is ever issued for cache tail blocks beyond that
+  token's prefix — the length vector literally steers which HBM blocks
+  move, per token.
+* inside a block the kernel reads the (bkv, nkv, hd) tile as bkv * nkv rows
+  and scores every query head of the token against all of them; a
+  head-match mask keeps, for each query head, the rows of its own KV head.
+  That is nkv times the MXU and score work of a walk over the heads, and
+  still cheaper: gathering one head's rows out of the stored tiles costs
+  Mosaic a load per row, or a shuffle of every vreg of the block.  The
+  reshape is free where nkv is a multiple of 8 (each cache row's (nkv, hd)
+  is then whole (8, 128) tiles); elsewhere Mosaic moves sublanes to make it.
+  Block rows come from the shape (``_block_rows``: one K block of about
+  ``KV_BLOCK_BYTES`` of cache, its f32 working copy at most four times
+  that, a narrow cache's rows padded to whole tiles).
+* "as the model stores it" is the TPU's default layout for an array of the
+  cache's shape.  That is row-major, which the kernel's operands must be,
+  wherever the (8, 128) tiling of (nkv, hd) wastes little; at some widths
+  (nkv 1 or 20, hd 96) XLA orders the cache otherwise, and then copies it
+  to row-major before the launch.
 * per-token lengths double as the intra-launch causal mask between draft
   tokens: token t's length is ``base + t + 1``, so draft token t sees draft
   tokens < t and nothing after — speculative causality needs no extra mask
@@ -21,9 +41,11 @@ from whole-loop schedules to tile-granular plans:
 * per-sequence lengths (ragged continuous batching) are the same vector with
   a batch-major stride — one launch serves sequences at different depths.
 
-``pl.when(kv_base < length)`` skips the compute for re-mapped steps and an
-in-block iota mask handles the ragged last block, exactly as in the scalar
-kernel — per (b, t) the math (block order, online-softmax updates) is
+``pl.when(kv_base < length)`` skips the compute for re-mapped steps.  S need
+not be a multiple of bkv: rows at or past a token's length are masked out of
+the scores by an in-block iota mask and zeroed in V, since the last block
+may run past the cache's end, where the tile holds anything, and 0 * NaN is
+NaN.  Per (b, t) the math (block order, online-softmax updates) is
 IDENTICAL to a one-token launch, so a T-token launch is bitwise equal to T
 sequential launches.
 
@@ -81,29 +103,34 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import resolve_interpret, shard_over_heads
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+# cache bytes of one K (or V) block of flash_decode: K and V, double-buffered,
+# take four times this (more where a narrow cache's rows pad to whole tiles),
+# and their f32 working copies up to eight times, inside the 16 MiB of
+# scoped VMEM a v5e kernel gets (see _block_rows)
+KV_BLOCK_BYTES = 512 * 1024
 
 
-def _load_kv_tile(kv_ref, scl_ref, row: int):
-    """Load one (bkv, hd) KV tile as f32, dequantizing in place when the
-    cache is int8: the per-token scales arrive as a (2, 1, bkv, 1) VMEM block
-    that follows the KV tile (row 0 = K scales, row 1 = V scales) and are
-    multiplied right after the tile load — BEFORE any dot — so the kernel is
+def _load_kv_tile(tile, scl_ref, row: int):
+    """A (bkv, hd) or (bkv, nkv, hd) KV tile as f32, dequantized in place
+    when the cache is int8: the per-row scales arrive as a (2, 1, bkv, 1)
+    VMEM block that follows the KV block (row 0 = K scales, row 1 = V
+    scales; one scale per cache row, shared by its heads) and are multiplied
+    right after the tile load — BEFORE any dot — so the kernel is
     bitwise-equal to running the unquantized kernel on the jnp-dequantized
     buffer."""
-    x = kv_ref[0, 0].astype(jnp.float32)  # (bkv, hd)
+    x = tile.astype(jnp.float32)
     if scl_ref is not None:
-        x = x * scl_ref[row, 0]  # (bkv, 1) per-row scales
+        s = scl_ref[row, 0]  # (bkv, 1) per-row scales
+        x = x * (s if x.ndim == 2 else s[:, :, None])
     return x
 
 
-def _online_softmax_step(q_ref, k_ref, v_ref, scl_ref, m_ref, l_ref, acc_ref, valid, scale, zero_masked):
-    """One KV block of the online softmax for one KV head's query group:
-    q (group, hd) against the (bkv, hd) tile, ``valid`` a (1, bkv) mask."""
-    q = q_ref[0, 0, 0].astype(jnp.float32)  # (group, hd)
-    k = _load_kv_tile(k_ref, scl_ref, 0)  # (bkv, hd)
+def _online_softmax_step(q, k, v, m_ref, l_ref, acc_ref, valid, scale, zero_masked):
+    """One KV block of the online softmax: q (rows, hd) against the f32
+    (bkv, hd) tiles k and v, ``valid`` a (rows or 1, bkv) mask."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (group, bkv)
+    ) * scale  # (rows, bkv)
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -116,7 +143,6 @@ def _online_softmax_step(q_ref, k_ref, v_ref, scl_ref, m_ref, l_ref, acc_ref, va
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
     m_ref[...] = m_new
-    v = _load_kv_tile(v_ref, scl_ref, 1)
     acc_ref[...] = acc_ref[...] * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
 
 
@@ -128,11 +154,13 @@ def _init_stats(m_ref, l_ref, acc_ref):
 
 def _store_out(o_ref, l_ref, acc_ref):
     l = jnp.maximum(l_ref[...], 1e-30)
-    o_ref[0, 0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc_ref[...] / l).reshape(o_ref.shape[2:]).astype(o_ref.dtype)
 
 
 def _flash_decode_kernel(len_ref, anc_ref, base_ref, *refs, bkv: int, n_kv: int,
                          scale: float, T: int, quantized: bool, paged: bool):
+    """One (b, t, block) step: every query head of token (b, t) against the
+    block's ``bkv`` cache rows of every KV head."""
     if paged:
         refs = refs[1:]  # the block table only steers the index_maps
     if quantized:
@@ -140,7 +168,7 @@ def _flash_decode_kernel(len_ref, anc_ref, base_ref, *refs, bkv: int, n_kv: int,
     else:
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
         scl_ref = None
-    b, t, ki = pl.program_id(0), pl.program_id(1), pl.program_id(3)
+    b, t, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -153,16 +181,34 @@ def _flash_decode_kernel(len_ref, anc_ref, base_ref, *refs, bkv: int, n_kv: int,
 
     @pl.when(kv_base < length)
     def _compute():
-        kv_pos = kv_base + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
+        # the (bkv, nkv, hd) block read as bkv * nkv rows: row r is head
+        # r % nkv of cache row kv_base + r // nkv (free where each cache
+        # row's (nkv, hd) is whole tiles of the stored layout: nkv % 8 == 0)
+        nkv, hd = k_ref.shape[2], k_ref.shape[3]
+        k, v = (_load_kv_tile(ref[0], scl_ref, i).reshape(bkv * nkv, hd)
+                for i, ref in enumerate((k_ref, v_ref)))
+        q = q_ref[0, 0].astype(jnp.float32)  # (nq, hd)
+        r = jax.lax.broadcasted_iota(jnp.int32, (1, bkv * nkv), 1)
+        kv_pos = kv_base + r // nkv
         # rows below base are shared committed prefix; draft row base + u is
         # visible iff bit u of this node's ancestor word is set (arithmetic
         # shift: the chain word -1 keeps every bit, reducing the mask to the
         # pure length clamp — bitwise the linear kernel)
         u = kv_pos - base
         on_path = (u < 0) | (jnp.right_shift(anc, jnp.clip(u, 0, 31)) & 1 > 0)
+        # every query head scores every row and keeps those of its own KV
+        # head: nkv times the MXU work of a walk over the heads, but far less
+        # than gathering each head's rows (Mosaic loads a row at a time, or
+        # shuffles every vreg of the block)
+        head = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0) // (q.shape[0] // nkv)
+        valid = (kv_pos < length) & on_path & (r % nkv == head)
+        # rows at or past the length are masked out of the scores, but may
+        # lie past the cache's end (the ragged last block), where the tile
+        # holds anything: zero them in V too, since 0 * NaN is NaN
+        live = kv_base + jax.lax.broadcasted_iota(jnp.int32, (bkv * nkv, 1), 0) // nkv < length
         _online_softmax_step(
-            q_ref, k_ref, v_ref, scl_ref, m_ref, l_ref, acc_ref,
-            (kv_pos < length) & on_path, scale, zero_masked=False,
+            q, k, jnp.where(live, v, 0.0), m_ref, l_ref, acc_ref, valid, scale,
+            zero_masked=False,
         )
 
     @pl.when(ki == n_kv - 1)
@@ -170,11 +216,11 @@ def _flash_decode_kernel(len_ref, anc_ref, base_ref, *refs, bkv: int, n_kv: int,
         _store_out(o_ref, l_ref, acc_ref)
 
 
-def _stat_scratch(group: int, hd: int):
+def _stat_scratch(rows: int, hd: int):
     return [
-        pltpu.VMEM((group, 1), jnp.float32),
-        pltpu.VMEM((group, 1), jnp.float32),
-        pltpu.VMEM((group, hd), jnp.float32),
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((rows, 1), jnp.float32),
+        pltpu.VMEM((rows, hd), jnp.float32),
     ]
 
 
@@ -189,23 +235,20 @@ def _group_q(q: jnp.ndarray, nkv: int) -> jnp.ndarray:
 @functools.partial(jax.jit, static_argnames=("bkv", "interpret"))
 def flash_decode_pallas(
     q: jnp.ndarray,        # (B, T, nq, hd) draft/step tokens
-    k: jnp.ndarray,        # (B, nkv, Skv, hd) full cache buffer
+    k: jnp.ndarray,        # (B, Skv, nkv, hd) full cache buffer, as the model stores it
     v: jnp.ndarray,
     lengths: jnp.ndarray,  # (B*T,) int32 valid prefix length per token, >= 1
     anc_words: Optional[jnp.ndarray] = None,  # (T,) int32 ancestor bitmasks
     base: Optional[jnp.ndarray] = None,       # (B,) int32 committed-prefix length
     scales: Optional[jnp.ndarray] = None,     # (2, B, Skv) f32 per-row K/V scales
     *,
-    bkv: int = 128,
+    bkv: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, T, nq, hd = q.shape
-    nkv, Skv = k.shape[1], k.shape[2]
-    group = nq // nkv
+    Skv, nkv = k.shape[1], k.shape[2]
     scale = 1.0 / math.sqrt(hd)
-    bkv = min(bkv, Skv)
-    assert Skv % bkv == 0, "pad the cache to a block multiple in ops"
-    n_kv = Skv // bkv
+    n_kv = pl.cdiv(Skv, bkv)
     quantized = scales is not None
     if anc_words is None:
         # chain default: all-ones words make the ancestor test vacuous and
@@ -214,7 +257,7 @@ def flash_decode_pallas(
     if base is None:
         base = jnp.zeros((B,), jnp.int32)
 
-    def kv_block(b, t, h, ki, len_ref):
+    def kv_block(b, t, ki, len_ref):
         # vector-steered: blocks past token (b, t)'s valid prefix re-map to
         # its last valid block (their compute is skipped), so their DMA never
         # happens — per-token clamping against the prefetched length vector.
@@ -223,46 +266,45 @@ def flash_decode_pallas(
         # topological-order invariant).
         return jnp.minimum(ki, (len_ref[b * T + t] - 1) // bkv)
 
-    def kv_map(b, t, h, ki, len_ref, *_):
-        return (b, h, kv_block(b, t, h, ki, len_ref), 0)
+    def kv_map(b, t, ki, len_ref, *_):
+        return (b, kv_block(b, t, ki, len_ref), 0, 0)
 
-    def qo_map(b, t, h, ki, *_):
-        return (b, t, h, 0, 0)
+    def qo_map(b, t, ki, *_):
+        return (b, t, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, 1, group, hd), qo_map),
-        pl.BlockSpec((1, 1, bkv, hd), kv_map),
-        pl.BlockSpec((1, 1, bkv, hd), kv_map),
+        pl.BlockSpec((1, 1, nq, hd), qo_map),
+        pl.BlockSpec((1, bkv, nkv, hd), kv_map),
+        pl.BlockSpec((1, bkv, nkv, hd), kv_map),
     ]
-    operands = [_group_q(q, nkv), k, v]
+    operands = [q, k, v]
     if quantized:
-        # the scale rows follow the KV tile through the same clamped map
+        # the scale rows follow the KV block through the same clamped map
         in_specs.append(pl.BlockSpec(
             (2, 1, bkv, 1),
-            lambda b, t, h, ki, len_ref, *_: (0, b, kv_block(b, t, h, ki, len_ref), 0),
+            lambda b, t, ki, len_ref, *_: (0, b, kv_block(b, t, ki, len_ref), 0),
         ))
         operands.append(scales.astype(jnp.float32).reshape(2, B, Skv, 1))
     kern = functools.partial(
         _flash_decode_kernel, bkv=bkv, n_kv=n_kv, scale=scale, T=T,
         quantized=quantized, paged=False,
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, T, nkv, n_kv),
+            grid=(B, T, n_kv),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, 1, group, hd), qo_map),
-            scratch_shapes=_stat_scratch(group, hd),
+            out_specs=pl.BlockSpec((1, 1, nq, hd), qo_map),
+            scratch_shapes=_stat_scratch(nq, hd),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, T, nkv, group, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, T, nq, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_decode",
     )(lengths, anc_words.astype(jnp.int32), base.astype(jnp.int32), *operands)
-    return out.reshape(B, T, nq, hd)
 
 
 # ---------------------------------------------------------------------------
@@ -297,7 +339,8 @@ def _flash_decode_window_kernel(pos_ref, *refs, bkv: int, n_kv: int, scale: floa
         abs_pos = head - jnp.remainder(write - slot, W)
         valid = (abs_pos >= 0) & (abs_pos <= pos) & (abs_pos > pos - window)
         _online_softmax_step(
-            q_ref, k_ref, v_ref, scl_ref, m_ref, l_ref, acc_ref, valid, scale,
+            q_ref[0, 0, 0].astype(jnp.float32), _load_kv_tile(k_ref[0, 0], scl_ref, 0),
+            _load_kv_tile(v_ref[0, 0], scl_ref, 1), m_ref, l_ref, acc_ref, valid, scale,
             zero_masked=True,
         )
 
@@ -384,7 +427,7 @@ def flash_decode_window_pallas(
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def flash_decode_paged_pallas(
     q: jnp.ndarray,        # (B, T, nq, hd)
-    k: jnp.ndarray,        # (P, nkv, page_size, hd) physical page pool
+    k: jnp.ndarray,        # (P, page_size, nkv, hd) physical page pool
     v: jnp.ndarray,
     lengths: jnp.ndarray,  # (B*T,) int32 valid prefix length per token, >= 1
     table: jnp.ndarray,    # (B*max_pages,) int32 flattened block tables
@@ -401,16 +444,16 @@ def flash_decode_paged_pallas(
     composes the existing per-token length clamp with a block-table lookup
     (``page = table[b, ki]``), so each DMA pulls the physical page backing
     that logical block — the int8 scale rows of that page follow through the
-    same map — while the in-kernel mask math (length clamp, ancestor words,
-    online softmax) is identical to :func:`flash_decode_pallas` at
-    ``bkv = page_size``.  With an identity table the chain default is
+    same map.  A page block is (1, page_size, nkv, hd), every KV head of the
+    page's rows as the pool stores them, so the kernel — mask math (length
+    clamp, ancestor words) and online softmax — is :func:`flash_decode_pallas`'s
+    at ``bkv = page_size``.  With an identity table the chain default is
     therefore bitwise-equal to the contiguous kernel — the same contract the
     all-ones ancestor words uphold for trees vs chains.
     """
     B, T, nq, hd = q.shape
-    P_, nkv, ps = k.shape[0], k.shape[1], k.shape[2]
+    P_, ps, nkv = k.shape[0], k.shape[1], k.shape[2]
     assert ps == page_size
-    group = nq // nkv
     scale = 1.0 / math.sqrt(hd)
     max_pages = table.shape[0] // B
     quantized = scales is not None
@@ -427,24 +470,24 @@ def flash_decode_paged_pallas(
         last = (len_ref[b * T + t] - 1) // ps
         return jnp.maximum(tbl_ref[b * max_pages + jnp.minimum(ki, last)], 0)
 
-    def kv_map(b, t, h, ki, len_ref, anc_ref, base_ref, tbl_ref):
-        return (page(b, t, ki, len_ref, tbl_ref), h, 0, 0)
+    def kv_map(b, t, ki, len_ref, anc_ref, base_ref, tbl_ref):
+        return (page(b, t, ki, len_ref, tbl_ref), 0, 0, 0)
 
-    def qo_map(b, t, h, ki, *_):
-        return (b, t, h, 0, 0)
+    def qo_map(b, t, ki, *_):
+        return (b, t, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, 1, group, hd), qo_map),
-        pl.BlockSpec((1, 1, ps, hd), kv_map),
-        pl.BlockSpec((1, 1, ps, hd), kv_map),
+        pl.BlockSpec((1, 1, nq, hd), qo_map),
+        pl.BlockSpec((1, ps, nkv, hd), kv_map),
+        pl.BlockSpec((1, ps, nkv, hd), kv_map),
     ]
-    operands = [_group_q(q, nkv), k, v]
+    operands = [q, k, v]
     if quantized:
         # scales are page metadata in pool-row order: the (2, P, ps, 1) view
         # lets the scale rows ride the page's own block-table lookup
         in_specs.append(pl.BlockSpec(
             (2, 1, ps, 1),
-            lambda b, t, h, ki, len_ref, anc_ref, base_ref, tbl_ref: (
+            lambda b, t, ki, len_ref, anc_ref, base_ref, tbl_ref: (
                 0, page(b, t, ki, len_ref, tbl_ref), 0, 0),
         ))
         operands.append(scales.astype(jnp.float32).reshape(2, P_, ps, 1))
@@ -452,18 +495,18 @@ def flash_decode_paged_pallas(
         _flash_decode_kernel, bkv=ps, n_kv=max_pages, scale=scale, T=T,
         quantized=quantized, paged=True,
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(B, T, nkv, max_pages),
+            grid=(B, T, max_pages),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, 1, group, hd), qo_map),
-            scratch_shapes=_stat_scratch(group, hd),
+            out_specs=pl.BlockSpec((1, 1, nq, hd), qo_map),
+            scratch_shapes=_stat_scratch(nq, hd),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, T, nkv, group, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, T, nq, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_decode_paged",
@@ -471,7 +514,6 @@ def flash_decode_paged_pallas(
         lengths, anc_words.astype(jnp.int32), base.astype(jnp.int32),
         table.reshape(-1).astype(jnp.int32), *operands,
     )
-    return out.reshape(B, T, nq, hd)
 
 
 # ---------------------------------------------------------------------------
@@ -504,7 +546,7 @@ def flash_decode(
     ancestors: Optional[jnp.ndarray] = None,  # (T,) int32 packed ancestor words
     base: Optional[jnp.ndarray] = None,       # (B,) int32 committed-prefix length
     scales: Optional[jnp.ndarray] = None,     # (2, B, Skv) per-row K/V scales (int8 cache)
-    bkv: int = 128,
+    bkv: Optional[int] = None,                # cache rows per block; None: from the shape
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Multi-token attention over each token's valid cache prefix.
@@ -525,27 +567,28 @@ def flash_decode(
     return shard_over_heads(run, args, (2, 2, 2, None, None, None, None))
 
 
+def _block_rows(S: int, nkv: int, hd: int, dtype) -> int:
+    """Cache rows per KV block: the most that keep one K block within
+    ``KV_BLOCK_BYTES`` of cache and its f32 working copy in VMEM, each row's
+    (nkv, hd) padded to whole (8, 128) tiles, within four times that (a
+    narrow cache, nkv < 8, pads up to 8x).  A multiple of 8 rows, so the
+    int8 scale block tiles; never more than the cache holds."""
+    f32_row = -(-nkv // 8) * 8 * (-(-hd // 128) * 128) * 4
+    rows = min(KV_BLOCK_BYTES // (nkv * hd * jnp.dtype(dtype).itemsize),
+               4 * KV_BLOCK_BYTES // f32_row)
+    return min(max(8, rows - rows % 8), S)
+
+
 def _flash_decode(q, k, v, cache_index, ancestors, base, scales, *, bkv, interpret):
     it = resolve_interpret(interpret)
     B, T, nq, hd = q.shape
-    Skv = k.shape[1]
-    bkv_ = min(bkv, Skv)
-    pad_kv = (-Skv) % bkv_
-    with jax.named_scope("attn.kv_read"):  # the cache into the kernel's layout
-        kt = jnp.swapaxes(k, 1, 2)
-        vt = jnp.swapaxes(v, 1, 2)
-        if pad_kv:
-            kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad_kv), (0, 0)))
-            vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad_kv), (0, 0)))
-        scl = None
-        if scales is not None:
-            scl = jnp.asarray(scales, jnp.float32)
-            if pad_kv:  # pad with ones: padded rows are masked but still multiplied
-                scl = jnp.pad(scl, ((0, 0), (0, 0), (0, pad_kv)), constant_values=1.0)
-    lengths = _as_length_vector(cache_index, B, T)
+    S, nkv = k.shape[1], k.shape[2]
+    bkv = _block_rows(S, nkv, hd, k.dtype) if bkv is None else min(bkv, S)
+    with jax.named_scope("attn.kv_read"):  # the control words; the cache is read in place
+        lengths = _as_length_vector(cache_index, B, T)
     return flash_decode_pallas(
-        q, kt, vt, lengths, anc_words=ancestors, base=base, scales=scl,
-        bkv=bkv_, interpret=it,
+        q, k, v, lengths, anc_words=ancestors, base=base, scales=scales,
+        bkv=bkv, interpret=it,
     )
 
 
@@ -621,11 +664,9 @@ def _flash_decode_paged(q, k, v, cache_index, pages, ancestors, base, scales, *,
     R = k.shape[0]
     assert R % page_size == 0, "pool rows must be a whole number of pages"
     P = R // page_size
-    with jax.named_scope("attn.kv_read"):  # the page pool into the kernel's layout
-        kt = jnp.swapaxes(k.reshape(P, page_size, *k.shape[1:]), 1, 2)
-        vt = jnp.swapaxes(v.reshape(P, page_size, *v.shape[1:]), 1, 2)
-    lengths = _as_length_vector(cache_index, B, T)
+    with jax.named_scope("attn.kv_read"):  # the control words; the pool is read in place
+        lengths = _as_length_vector(cache_index, B, T)
     return flash_decode_paged_pallas(
-        q, kt, vt, lengths, pages.reshape(-1), anc_words=ancestors, base=base,
+        q, k.reshape(P, page_size, *k.shape[1:]), v.reshape(P, page_size, *v.shape[1:]), lengths, pages.reshape(-1), anc_words=ancestors, base=base,
         scales=scales, page_size=page_size, interpret=it,
     )

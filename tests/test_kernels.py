@@ -196,6 +196,11 @@ def test_ssd_scan_unpadded_tail():
 # ---------------------------------------------------------------------------
 
 TREE4 = (1, 3, 5, 11)  # TreePlan.from_branching([2, 1]) ancestor words
+# flash_decode reads the cache in place at other widths, (nq, nkv, hd): the
+# dense cell's GQA 8 x 128, MHA (group 1), hd 96. S = 37 leaves a ragged last
+# block of 8 rows, and every cache row past each slot's last token holds NaN
+RAGGED = {"ragged_gqa": (64, 8, 128), "ragged_tree": (64, 8, 128),
+          "ragged_mha": (4, 4, 16), "ragged_hd96": (8, 2, 96)}
 
 
 def _kv(rng, shape, quant):
@@ -209,13 +214,15 @@ def _kv(rng, shape, quant):
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
-@pytest.mark.parametrize("kind", ["chain", "tree", "window", "paged", "paged_tree"])
+@pytest.mark.parametrize("kind", ["chain", "tree", "window", "paged", "paged_tree", *RAGGED])
 def test_flash_decode_kernels_match_oracle(kind, quant):
     from repro.kernels.flash_attention import decode as kern
     from repro.kernels.flash_attention import ref
 
     rng = np.random.default_rng(len(kind) + quant)
     B, nq, nkv, hd, S, ps = 2, 4, 2, 16, 32, 8
+    if kind in RAGGED:
+        (nq, nkv, hd), S = RAGGED[kind], 37
     T = 4 if "tree" in kind else 2
     q = jnp.asarray(rng.standard_normal((B, T, nq, hd)), jnp.float32)
     (k, ks), (v, vs) = _kv(rng, (B, S, nkv, hd), quant), _kv(rng, (B, S, nkv, hd), quant)
@@ -237,6 +244,14 @@ def test_flash_decode_kernels_match_oracle(kind, quant):
                                       interpret=True, **tree)
         want = ref.flash_decode_paged(q, pk, pv, idx, perm, page_size=ps, scales=pscl, **tree)
     else:
-        got = kern.flash_decode(q, k, v, idx, scales=scl, bkv=8, interpret=True, **tree)
+        kn, vn, sn = k, v, scl
+        if kind in RAGGED:  # poison what no token may see: the kernel must not read it
+            dead = jnp.arange(S)[None, :] >= (idx + T)[:, None]  # (B, S)
+            if quant:
+                sn = jnp.where(dead, jnp.nan, scl)
+            else:
+                kn, vn = (jnp.where(dead[:, :, None, None], jnp.nan, c) for c in (k, v))
+        got = kern.flash_decode(q, kn, vn, idx, scales=sn, bkv=8, interpret=True, **tree)
         want = ref.flash_decode(q, k, v, idx, scales=scl, **tree)
+        assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)

@@ -1,6 +1,7 @@
 """Compile rehearsals: every Pallas kernel the served path reaches on TPU,
 lowered and compiled for a described (unattached) TPU v5e at the widths of
-the ``qwen3-moe-235b-a22b-1chip`` preset.
+the ``qwen3-moe-235b-a22b-1chip`` preset; ``flash_decode`` also at the dense
+cell's shapes (with a guard on its program) and at other head widths.
 
 Nothing runs: these compiles catch what interpret mode cannot — block shapes
 that break the (8, 128) tiling rule, scoped-VMEM overflows, SMEM tables that
@@ -13,7 +14,9 @@ read back without one.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import pytest
 
@@ -84,6 +87,51 @@ def test_flash_decode_compiles(one_chip, T, quant):
     if quant:
         shapes.append(((2, B, SKV), jnp.float32))
     _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_flash_decode_reads_cache_in_place(one_chip, quant):
+    """At the dense cell's shapes (32 slots x 3,073 rows, 64 query / 8 KV
+    heads of 128) the kernel takes the cache as the model stores it: no
+    copy, transpose or pad of a cache-sized array in the optimised HLO."""
+    from repro.kernels.flash_attention import flash_decode
+
+    B_, S_, nq, nkv, hd = 32, 3073, 64, 8, 128
+    kv_dt = jnp.int8 if quant else jnp.bfloat16
+
+    def fn(q, k, v, idx, *scl):
+        return flash_decode(q, k, v, idx, scales=scl[0] if scl else None, interpret=False)
+
+    shapes = [((B_, 1, nq, hd), jnp.bfloat16), ((B_, S_, nkv, hd), kv_dt),
+              ((B_, S_, nkv, hd), kv_dt), ((B_,), jnp.int32)]
+    if quant:
+        shapes.append(((2, B_, S_), jnp.float32))
+    hlo = _compile(fn, one_chip, *shapes).as_text()
+    moved = [
+        line.strip() for line in hlo.splitlines()
+        for dims in re.findall(r"= \w+\[([\d,]+)\]\S* (?:copy|transpose|pad)\(", line)
+        if math.prod(int(d) for d in dims.split(",")) >= B_ * S_ * nkv * hd
+    ]
+    assert not moved, moved
+
+
+# (nq, nkv, hd): MHA, hd 96, qwen3-moe-235b-a22b per chip of four (its 4 KV
+# heads split over model=4), starcoder2-3b, qwen1.5-4b, stablelm-12b
+OTHER_WIDTHS = {"mha": (32, 32, 128), "hd96": (32, 8, 96), "moe_4chip_shard": (16, 1, 128),
+                "starcoder2": (24, 2, 128), "qwen15": (20, 20, 128), "stablelm": (32, 8, 160)}
+
+
+@pytest.mark.parametrize("widths", list(OTHER_WIDTHS.values()), ids=list(OTHER_WIDTHS))
+def test_flash_decode_compiles_at_other_widths(one_chip, widths):
+    from repro.kernels.flash_attention import flash_decode
+
+    nq, nkv, hd = widths
+
+    def fn(q, k, v, idx):
+        return flash_decode(q, k, v, idx, interpret=False)
+
+    _compile(fn, one_chip, ((8, 1, nq, hd), jnp.bfloat16), ((8, 1000, nkv, hd), jnp.bfloat16),
+             ((8, 1000, nkv, hd), jnp.bfloat16), ((8,), jnp.int32))
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
