@@ -4,7 +4,9 @@ configuration (:func:`bench.weights.dims_of`).
 A token at context ``c`` (it attends to ``c`` rows, itself included) costs,
 per layer, the q/k/v/o projections ``2 d (nq + 2 nkv) hd + 2 nq hd d``,
 attention ``4 nq hd c``, and the FFN: ``6 d ff`` dense, or for MoE the
-router ``2 d E`` and ``6 d ff`` for each of its ``top_k`` experts.  A
+router ``2 d R`` at its published width R and ``6 d ff`` for each of the
+``top_k`` experts a token routes to, scaled by the held share ``E / R``: the
+chip's expected part of them (all of them where every expert is held).  A
 token whose logits are computed adds the head, ``2 d V``.  Prefill computes
 logits for the last prompt token only; decode for every token.
 """
@@ -17,7 +19,8 @@ def _fixed(dm: Dict[str, int]) -> float:
     d, nq, nkv, hd, ff = dm["d"], dm["nq"], dm["nkv"], dm["hd"], dm["ff"]
     proj = 2.0 * d * (nq + 2 * nkv) * hd + 2.0 * nq * hd * d
     if dm["experts"]:
-        ffn = 2.0 * d * dm["experts"] + 6.0 * d * ff * dm["top_k"]
+        R = dm["router"]
+        ffn = 2.0 * d * R + 6.0 * d * ff * dm["top_k"] * dm["experts"] / R
     else:
         ffn = 6.0 * d * ff
     return proj + ffn

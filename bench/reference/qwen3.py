@@ -15,6 +15,9 @@ attention scaled by ``1/sqrt(head_dim)``, a SwiGLU FFN
 ``down(silu(gate(x)) * up(x))``, and for MoE a softmax router over the
 experts, the top ``num_experts_per_tok`` kept and renormalised
 (``norm_topk_prob``), every token served by its experts with none dropped.
+Where the configuration holds a share of the experts (``num_experts`` held
+of ``reduced_from.num_experts``, share ``expert_share``), the router keeps
+its published width and the layer adds only the held experts' part.
 Logits are ``rms(x) @ unembed^T``.  Every matrix product runs at
 ``jax.default_matmul_precision("highest")``.
 
@@ -108,26 +111,37 @@ def swiglu(x, wg, wu, wd, quant):
 
 
 def moe(x, w, hf, quant, chunk=2048):
-    """Top-k of the softmax router, renormalised; every token to its experts."""
+    """The held experts' part of the MoE layer: the softmax router over all
+    ``router`` experts, its top k kept and renormalised, and each token's
+    gate-weighted SwiGLUs of the held experts it routes to, in expert order.
+    With every expert held that is the whole layer.
+
+    The experts are a ``lax.scan`` over their stacks, the gate column of
+    each a scanned input, so the compiled program is one expert's, whatever
+    the count; every token goes through every held expert."""
     T, d = x.shape
-    E, k = hf["num_experts"], hf["num_experts_per_tok"]
-    probs = jax.nn.softmax(x @ w["moe/router"], axis=-1)  # (T, E)
-    top_w, top_e = jax.lax.top_k(probs, k)
+    dims = W.dims_of(hf)
+    held, first = dims["experts"], dims["expert0"]
+    probs = jax.nn.softmax(x @ w["moe/router"], axis=-1)  # (T, router)
+    top_w, top_e = jax.lax.top_k(probs, dims["top_k"])
     if hf.get("norm_topk_prob", True):
         top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
-    gate = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], top_e].set(top_w)
+    gate = jnp.zeros_like(probs).at[jnp.arange(T)[:, None], top_e].set(top_w)
+    gate = gate[:, first:first + held]
+    stacks = (w["moe/w_gate"], w["moe/w_up"], w["moe/w_down"])
 
     def one_chunk(args):
-        xc, gc = args
-        y = jnp.zeros_like(xc)
-        for e in range(E):  # every expert on every token, weighted by its gate
-            y = y + gc[:, e:e + 1] * swiglu(
-                xc, w["moe/w_gate"][e], w["moe/w_up"][e], w["moe/w_down"][e], quant)
-        return y
+        xc, gc = args  # gc (held, chunk): the gate columns
+
+        def one_expert(y, e):
+            wg, wu, wd, g = e
+            return y + g[:, None] * swiglu(xc, wg, wu, wd, quant), None
+
+        return jax.lax.scan(one_expert, jnp.zeros_like(xc), stacks + (gc,))[0]
 
     pad = (-T) % chunk
     xp = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, chunk, d)
-    gp = jnp.pad(gate, ((0, pad), (0, 0))).reshape(-1, chunk, E)
+    gp = jnp.pad(gate, ((0, pad), (0, 0))).reshape(-1, chunk, held).transpose(0, 2, 1)
     return jax.lax.map(one_chunk, (xp, gp)).reshape(-1, d)[:T]
 
 

@@ -103,7 +103,9 @@ def device_guard(chips: int):
 def program_config(hf: Dict):
     """The program's ModelConfig: the registered preset the file names, with
     each override applied where the field exists; then every published key
-    the program has a field for must agree with it."""
+    the program has a field for must agree with it (for a share of the
+    experts: ``reduced_from.num_experts`` with ``num_experts``, the held
+    ``num_experts`` with ``experts_held``)."""
     from repro.configs import get_config
 
     prog = hf["program"]
@@ -114,12 +116,18 @@ def program_config(hf: Dict):
     if skipped:
         log(f"bench: overrides skipped (no such field in the program): {skipped}")
     cfg = dataclasses.replace(base, **over)
+    # with a share of the experts, the program's num_experts is the router's
+    # published width, and experts_held (where it has one) the experts held
+    router = hf.get("reduced_from", {}).get("num_experts")
+    want = dict(hf, num_experts=router) if router else hf
     wrong = []
     for key, field in FIELDS.items():
-        if key in hf and field in names:
+        if key in want and field in names:
             got = cfg.resolved_head_dim if field == "head_dim" else getattr(cfg, field)
-            if got != hf[key]:
-                wrong.append(f"{key}={hf[key]} but {field}={got}")
+            if got != want[key]:
+                wrong.append(f"{key}={want[key]} but {field}={got}")
+    if router and "experts_held" in names and cfg.experts_held != hf["num_experts"]:
+        wrong.append(f"num_experts={hf['num_experts']} held but experts_held={cfg.experts_held}")
     if not hf.get("num_experts") and "d_ff" in names and cfg.d_ff != hf["intermediate_size"]:
         wrong.append(f"intermediate_size={hf['intermediate_size']} but d_ff={cfg.d_ff}")
     if wrong:
@@ -366,7 +374,9 @@ def run_cell(c: Dict, seed: int, seconds: float, trace: bool, *,
         metrics["setup_s"] = {"value": setup_s, "unit": "s"}
         reduced = None
 
+    t_check = time.perf_counter()
     verdict = CK.check(hf, seed, rec.finished, prompts, limits, outputs, control=control)
+    check_s = time.perf_counter() - t_check
     if extras is not None:
         extras.update(rec=rec, prompts=prompts, verdict=verdict)
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
@@ -383,8 +393,8 @@ def run_cell(c: Dict, seed: int, seconds: float, trace: bool, *,
         f"{len(rec.finished)} finished, {in_window_compiles} compiles in the window, "
         f"setup {setup_s:.3f} s")
     log(f"bench: checked {len(verdict['sampled'])} requests, "
-        f"{sum(len(rec.finished[x]) for x in verdict['sampled'])} served tokens; gaps "
-        + json.dumps(verdict["stats"]))
+        f"{sum(len(rec.finished[x]) for x in verdict['sampled'])} served tokens in "
+        f"{check_s:.3f} s; gaps " + json.dumps(verdict["stats"]))
     if "control" in verdict:
         for name, v in verdict["control"]["numbers"].items():
             log(f"control {name} {v['value']!r} limit {v['limit']!r}")

@@ -182,5 +182,8 @@ def test_model_flops_by_hand():
     # per layer and token: projections 64 + 32, FFN 72; attention 16 per row
     assert model.decode(dm, [1, 2]) == 2 * 168 + 16 * 3 + 2 * 2 * 4 * 5
     assert model.prefill(dm, 2) == 2 * 168 + 16 * 3 + 2 * 4 * 5
-    moe = dict(dm, experts=4, top_k=2)
+    moe = dict(dm, experts=4, router=4, top_k=2)
     assert model.decode(moe, [1]) == 96 + 2 * 4 * 4 + 6 * 4 * 3 * 2 + 16 + 40
+    # a share of 1 of 4 experts: the router at its width, a quarter of the routed FFN
+    share = dict(moe, experts=1)
+    assert model.decode(share, [1]) == 96 + 2 * 4 * 4 + 6 * 4 * 3 * 2 / 4 + 16 + 40

@@ -15,6 +15,13 @@ asks for it.  Two maps read it:
   ``sqrt(d_model)`` after the lookup, so the draw is what it stores.
 
 Layers are drawn one at a time, so the reference can hold one layer at once.
+
+An MoE layer's router is drawn at its published width (``router``), and each
+expert's ``w_gate``, ``w_up`` and ``w_down`` from a key that folds in the
+expert's id, so that the stacks of a share of the experts are slices of the
+uncut layer's.  A configuration holds a share as ``num_experts`` held of
+``reduced_from.num_experts``, and ``expert_share`` s (default 0) says which:
+ids ``[s * held, (s + 1) * held)``.
 """
 from __future__ import annotations
 
@@ -26,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 NORMS = ("ln1", "ln2", "final_norm", "attn/q_norm", "attn/k_norm")
+EXPERTS = ("moe/w_gate", "moe/w_up", "moe/w_down")  # stacks on axis 0, by expert id
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -36,14 +44,22 @@ def seed_key(seed: int) -> jax.Array:
 
 
 def dims_of(hf: Dict) -> Dict[str, int]:
-    """The sizes the draws need, from a configuration file's published keys."""
+    """The sizes the draws need, from a configuration file's published keys:
+    ``experts`` held, of ``router`` (the router's width), from id ``expert0``."""
     moe = "num_experts" in hf
+    held = hf.get("num_experts", 0)
+    router = hf.get("reduced_from", {}).get("num_experts", held)
+    share = hf.get("expert_share", 0)
+    if moe and not (0 <= share and (share + 1) * held <= router):
+        raise ValueError(f"expert_share {share} of {held} held experts lies outside "
+                         f"the router's {router}")
     return {
         "d": hf["hidden_size"], "nq": hf["num_attention_heads"],
         "nkv": hf["num_key_value_heads"], "hd": hf["head_dim"],
         "layers": hf["num_hidden_layers"], "vocab": hf["vocab_size"],
         "ff": hf["moe_intermediate_size"] if moe else hf["intermediate_size"],
-        "experts": hf.get("num_experts", 0), "top_k": hf.get("num_experts_per_tok", 0),
+        "experts": held, "router": router, "expert0": share * held,
+        "top_k": hf.get("num_experts_per_tok", 0),
     }
 
 
@@ -62,9 +78,17 @@ def _std(name: str, shape: Tuple[int, ...], dims: Dict[str, int]) -> float:
 
 def draw(key: jax.Array, name: str, layer: int, shape: Tuple[int, ...],
          dims: Dict[str, int]) -> jax.Array:
-    """The bfloat16 draw of tensor ``name`` of ``layer`` (-1: outside layers)."""
+    """The bfloat16 draw of tensor ``name`` of ``layer`` (-1: outside layers).
+    An expert stack's rows are experts ``expert0``, ``expert0 + 1``, ...,
+    each drawn from the tensor's key folded with its id."""
     k = jax.random.fold_in(jax.random.fold_in(key, zlib.crc32(name.encode())), layer + 1)
-    x = jax.random.normal(k, shape, jnp.float32) * _std(name, shape, dims)
+    std = _std(name, shape, dims)
+    if name in EXPERTS:
+        ids = dims["expert0"] + jnp.arange(shape[0])
+        one = lambda e: jax.random.normal(jax.random.fold_in(k, e), shape[1:], jnp.float32)
+        x = jax.vmap(one)(ids) * std
+    else:
+        x = jax.random.normal(k, shape, jnp.float32) * std
     return x.astype(jnp.bfloat16)
 
 
@@ -86,7 +110,7 @@ def layer_shapes(dims: Dict[str, int]) -> Dict[str, Tuple[int, ...]]:
         "attn/wo": (nq, hd, d), "attn/q_norm": (hd,), "attn/k_norm": (hd,),
     }
     if E:
-        shapes.update({"moe/router": (d, E), "moe/w_gate": (E, d, ff),
+        shapes.update({"moe/router": (d, dims["router"]), "moe/w_gate": (E, d, ff),
                        "moe/w_up": (E, d, ff), "moe/w_down": (E, ff, d)})
     else:
         shapes.update({"ffn/w_gate": (d, ff), "ffn/w_up": (d, ff), "ffn/w_down": (ff, d)})
@@ -125,7 +149,9 @@ def program_params(key: jax.Array, abstract, dims: Dict[str, int], pattern_len: 
     """The program's parameter tree filled with the draws, leaf by leaf in
     the program's dtypes.  ``abstract`` is the program's tree of shapes:
     ``blocks/scan/b<j>/...`` leaves stack super-blocks on axis 0,
-    ``blocks/rest/<i>/...`` are the layers after them.  Call under ``jit``."""
+    ``blocks/rest/<i>/...`` are the layers after them.  An expert stack is
+    filled id by id from the held range, as :func:`draw` gives it.  Call
+    under ``jit``."""
     n_sb = 0
     for path, leaf in jax.tree_util.tree_flatten_with_path(abstract)[0]:
         names = _path_names(path)
