@@ -7,7 +7,7 @@ row read once (with ``rows`` assignments over ``experts`` experts, at most
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 
 def work(rows: int, experts: int, K: int, N: int,
@@ -17,11 +17,17 @@ def work(rows: int, experts: int, K: int, N: int,
     return flops, bytes_
 
 
-def swiglu(tokens: int, top_k: int, experts: int, d: int, f: int) -> Tuple[float, float]:
+def swiglu(tokens: int, top_k: int, experts: int, d: int, f: int, *,
+           held: Optional[int] = None, router: Optional[int] = None) -> Tuple[float, float]:
     """The three launches of one MoE layer's experts: gate and up (d -> f),
-    down (f -> d), over ``tokens * top_k`` routed rows."""
-    rows = tokens * top_k
-    parts = [work(rows, experts, d, f), work(rows, experts, d, f), work(rows, experts, f, d)]
+    down (f -> d).  A chip that holds ``held`` of the router's ``router``
+    experts (both default to ``experts``) computes the
+    ``tokens * top_k * held / router`` routed rows that fall to its experts,
+    on weights of at most ``min(rows, held)`` of them."""
+    held = experts if held is None else held
+    router = experts if router is None else router
+    rows = tokens * top_k * held / router
+    parts = [work(rows, held, d, f), work(rows, held, d, f), work(rows, held, f, d)]
     return sum(p[0] for p in parts), sum(p[1] for p in parts)
 
 # names of its device operations in a profiler trace

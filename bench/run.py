@@ -103,9 +103,11 @@ def device_guard(chips: int):
 def program_config(hf: Dict):
     """The program's ModelConfig: the registered preset the file names, with
     each override applied where the field exists; then every published key
-    the program has a field for must agree with it (for a share of the
-    experts: ``reduced_from.num_experts`` with ``num_experts``, the held
-    ``num_experts`` with ``experts_held``)."""
+    the program has a field for must agree with it.  For a share of the
+    experts ``reduced_from.num_experts`` is the router's width, the
+    program's ``num_experts``; where the program has the fields, the file's
+    held ``num_experts`` must be its ``experts_held`` (0: every expert) and
+    the file's ``expert_share`` (default 0) its ``expert_share``."""
     from repro.configs import get_config
 
     prog = hf["program"]
@@ -116,8 +118,6 @@ def program_config(hf: Dict):
     if skipped:
         log(f"bench: overrides skipped (no such field in the program): {skipped}")
     cfg = dataclasses.replace(base, **over)
-    # with a share of the experts, the program's num_experts is the router's
-    # published width, and experts_held (where it has one) the experts held
     router = hf.get("reduced_from", {}).get("num_experts")
     want = dict(hf, num_experts=router) if router else hf
     wrong = []
@@ -126,9 +126,15 @@ def program_config(hf: Dict):
             got = cfg.resolved_head_dim if field == "head_dim" else getattr(cfg, field)
             if got != want[key]:
                 wrong.append(f"{key}={want[key]} but {field}={got}")
-    if router and "experts_held" in names and cfg.experts_held != hf["num_experts"]:
-        wrong.append(f"num_experts={hf['num_experts']} held but experts_held={cfg.experts_held}")
-    if not hf.get("num_experts") and "d_ff" in names and cfg.d_ff != hf["intermediate_size"]:
+    if hf.get("num_experts"):
+        held = hf["num_experts"]
+        if "experts_held" in names and (cfg.experts_held or cfg.num_experts) != held:
+            wrong.append(f"num_experts={held} held but the program holds "
+                         f"experts_held={cfg.experts_held} of num_experts={cfg.num_experts}")
+        share = hf.get("expert_share", 0)
+        if "expert_share" in names and cfg.expert_share != share:
+            wrong.append(f"expert_share={share} but expert_share={cfg.expert_share}")
+    elif "d_ff" in names and cfg.d_ff != hf["intermediate_size"]:
         wrong.append(f"intermediate_size={hf['intermediate_size']} but d_ff={cfg.d_ff}")
     if wrong:
         raise SystemExit(f"bench: the program does not run the configuration: {wrong}")

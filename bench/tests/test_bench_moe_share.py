@@ -1,11 +1,13 @@
 """The reference's MoE and its weight draws at tiny sizes on the CPU: the
 scanned expert loop against the loop over every expert it replaced, a share of
 the experts (``num_experts`` held of ``reduced_from.num_experts``) against
-the uncut layer, draws that compose, and a compiled program whose size does
-not grow with the number of experts."""
+the uncut layer, draws that compose, a compiled program whose size does
+not grow with the number of experts, and the published-key check of the
+program's router width, held count and share."""
 from __future__ import annotations
 
 import copy
+import dataclasses
 import hashlib
 import json
 from pathlib import Path
@@ -19,6 +21,8 @@ from bench import run, weights as W
 from bench.kernels import model
 from bench.reference import qwen3
 from bench.tests import tiny
+from repro import configs
+from repro.configs import ModelConfig
 
 SEED = 2**31 + 11
 DATA = Path(__file__).resolve().parent / "data"
@@ -27,11 +31,12 @@ DATA = Path(__file__).resolve().parent / "data"
 def moe_hf(experts=8, top_k=2, *, held=None, share=0, norm=True):
     """The tiny MoE file with ``experts`` behind the router; with ``held``,
     this chip holds share ``share`` of them."""
-    hf = tiny.config(tiny.MOE, dtype="float32")
-    hf.update(num_experts=experts, num_experts_per_tok=top_k, norm_topk_prob=norm)
-    if held is not None:
-        hf.update(num_experts=held, expert_share=share,
-                  reduced_from=dict(hf["reduced_from"], num_experts=experts))
+    hf = tiny.config(tiny.MOE, dtype="float32", held=held, share=share)
+    hf.update(num_experts_per_tok=top_k, norm_topk_prob=norm)
+    if held is None:
+        hf["num_experts"] = experts
+    else:
+        hf["reduced_from"]["num_experts"] = experts
     return hf
 
 
@@ -191,18 +196,62 @@ def test_reference_block_is_one_program_whatever_the_expert_count():
     assert n8 > 3 and n8 == _dot_generals(128)
 
 
+def share_file():
+    return json.loads((DATA / "qwen3-235b-a22b.16of128.json").read_text())
+
+
 def test_program_config_takes_a_share_at_the_router_width():
-    hf = json.loads((DATA / "qwen3-235b-a22b.16of128.json").read_text())
+    hf = share_file()
     cfg = run.program_config(hf)          # the program's 128 experts: the router
     assert cfg.num_experts == 128 and W.dims_of(hf)["experts"] == 16
-    bad = copy.deepcopy(hf)
-    bad["program"]["preset"] = "qwen3-moe-235b-a22b-1chip"   # routes over its 16
+    bad = copy.deepcopy(hf)               # a router over only the 16 held
+    bad["program"]["overrides"]["num_experts"] = 16
     with pytest.raises(SystemExit):
         run.program_config(bad)
     bad = copy.deepcopy(hf)
     bad["reduced_from"]["num_experts"] = 64
     with pytest.raises(SystemExit):
         run.program_config(bad)
+
+
+# the program's config with the two fields by which a program says which
+# experts it holds, whether or not its own config has them
+HeldConfig = dataclasses.make_dataclass(
+    "HeldConfig", [("experts_held", int, 0), ("expert_share", int, 0)],
+    bases=(ModelConfig,), frozen=True)
+
+
+@pytest.fixture
+def holding_program(monkeypatch):
+    program = configs.get_config
+
+    def get(name):
+        base = program(name)
+        return HeldConfig(**{f.name: getattr(base, f.name) for f in dataclasses.fields(base)})
+
+    monkeypatch.setattr(configs, "get_config", get)
+
+
+def test_program_config_takes_the_programs_held_count_and_share(holding_program):
+    cfg = run.program_config(share_file())
+    assert (cfg.num_experts, cfg.experts_held, cfg.expert_share) == (128, 16, 0)
+    hf = tiny.config(tiny.MOE, held=2, share=1)
+    cfg = run.program_config(hf)
+    assert (cfg.num_experts, cfg.experts_held, cfg.expert_share) == (8, 2, 1)
+    assert W.dims_of(hf)["expert0"] == 2
+
+
+@pytest.mark.parametrize("override, named", [
+    ({"experts_held": 0}, ["num_experts=16", "experts_held=0", "num_experts=128"]),
+    ({"experts_held": 128}, ["num_experts=16", "experts_held=128"]),
+    ({"expert_share": 1}, ["expert_share=0", "expert_share=1"]),
+])
+def test_program_config_refuses_another_held_count_or_share(holding_program, override, named):
+    hf = share_file()
+    hf["program"]["overrides"].update(override)
+    with pytest.raises(SystemExit) as err:
+        run.program_config(hf)
+    assert all(n in str(err.value) for n in named), str(err.value)
 
 
 @pytest.mark.parametrize("name", ["qwen3-235b-a22b.16of128", "qwen3-30b-a3b.6l"])

@@ -168,6 +168,11 @@ def test_grouped_gemm_work_by_hand():
     f, b = grouped_gemm.swiglu(3, 2, 4, 8, 16)          # 6 routed rows
     assert f == 3 * 2 * 6 * 8 * 16
     assert b == 3 * 4 * 8 * 16 * 2 + 3 * 6 * 24 * 2
+    # 4 tokens, top-2, 2 held of 8: 8 assignments, 2 of them to this chip
+    f, b = grouped_gemm.swiglu(4, 2, 8, 8, 16, held=2, router=8)
+    assert f == 3 * 2 * 2 * 8 * 16
+    assert b == 3 * 2 * 8 * 16 * 2 + 3 * 2 * 24 * 2
+    assert grouped_gemm.swiglu(4, 2, 8, 8, 16) == grouped_gemm.swiglu(4, 2, 8, 8, 16, held=8)
 
 
 def test_flash_attention_work_by_hand():

@@ -7,6 +7,7 @@ from __future__ import annotations
 import copy
 import json
 from pathlib import Path
+from typing import Optional
 
 from bench import run
 
@@ -22,24 +23,27 @@ DENSE = {
     "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2, "vocab_size": 256,
 }
 FIELDS = dict(run.FIELDS, intermediate_size="d_ff")
-# Qwen3-MoE (named after the program's one-chip preset it starts from) in
-# the form of a file under bench/configs: the published keys
-# the sizes above leave, and the program path that computes the published
-# block (each token routed on its own post-attention state, none dropped)
+# Qwen3-MoE in the form of a file under bench/configs, on the program's
+# published preset: the published keys the sizes above leave, and the program
+# path that computes the published block (each token routed on its own
+# post-attention state, none dropped).  The name keeps the test ids stable.
 MOE = "qwen3-moe-235b-a22b-1chip"
 MOE_FILE = {
     "model_type": "qwen3_moe", "norm_topk_prob": True, "rms_norm_eps": 1e-06,
     "rope_theta": 1000000.0, "tie_word_embeddings": False, "hidden_act": "silu",
     "reduced_from": {},
-    "program": {"preset": "qwen3-moe-235b-a22b-1chip",
+    "program": {"preset": "qwen3-moe-235b-a22b",
                 "overrides": {"decode_plane": False, "route_mode": "sync",
                               "rope_theta": 1000000.0, "spec_tokens": 1}},
 }
 
 
-def config(name: str, *, dtype: str = "bfloat16", slots: int = 4, **program) -> dict:
+def config(name: str, *, dtype: str = "bfloat16", slots: int = 4,
+           held: Optional[int] = None, share: int = 0, **program) -> dict:
     """A configuration cut to tiny sizes: ``MOE`` or the name of a file
-    under bench/configs; ``program`` adds overrides."""
+    under bench/configs; ``program`` adds overrides.  For ``MOE`` the
+    program holds every expert, or with ``held`` the share ``share`` of
+    ``held`` experts behind the router's tiny width."""
     if name == MOE:
         hf = copy.deepcopy(MOE_FILE)
     else:
@@ -49,8 +53,13 @@ def config(name: str, *, dtype: str = "bfloat16", slots: int = 4, **program) -> 
     over = hf["program"]["overrides"]
     over.update({FIELDS[k]: v for k, v in small.items()})
     if "num_experts" in hf:
-        over["capacity_factor"] = small["num_experts"] / small["num_experts_per_tok"]
+        router = small["num_experts"]
+        over["capacity_factor"] = router / small["num_experts_per_tok"]
         over["d_ff"] = small["moe_intermediate_size"]
+        if held is not None:
+            hf.update(num_experts=held, expert_share=share,
+                      reduced_from=dict(hf["reduced_from"], num_experts=router))
+        over.update(experts_held=hf["num_experts"], expert_share=hf.get("expert_share", 0))
     over.update(dtype=dtype, param_dtype=dtype, use_pallas=False)
     over.update(program)
     hf["program"]["slots"] = slots
