@@ -34,6 +34,10 @@ from whole-loop schedules to tile-granular plans:
   wherever the (8, 128) tiling of (nkv, hd) wastes little; at some widths
   (nkv 1 or 20, hd 96) XLA orders the cache otherwise, and then copies it
   to row-major before the launch.
+* the decode scan keeps its layers' caches stacked, (L, B, S, nkv, hd), and
+  writes each layer's new rows into the stack in place; with one more
+  prefetched word, the layer index, the KV index_maps take their blocks
+  straight out of the stack, so no layer is sliced out of it or put back.
 * per-token lengths double as the intra-launch causal mask between draft
   tokens: token t's length is ``base + t + 1``, so draft token t sees draft
   tokens < t and nothing after — speculative causality needs no extra mask
@@ -158,11 +162,11 @@ def _store_out(o_ref, l_ref, acc_ref):
 
 
 def _flash_decode_kernel(len_ref, anc_ref, base_ref, *refs, bkv: int, n_kv: int,
-                         scale: float, T: int, quantized: bool, paged: bool):
+                         scale: float, T: int, quantized: bool, steer_words: int):
     """One (b, t, block) step: every query head of token (b, t) against the
     block's ``bkv`` cache rows of every KV head."""
-    if paged:
-        refs = refs[1:]  # the block table only steers the index_maps
+    # the block table or the layer word only steers the index_maps
+    refs = refs[steer_words:]
     if quantized:
         q_ref, k_ref, v_ref, scl_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -235,21 +239,24 @@ def _group_q(q: jnp.ndarray, nkv: int) -> jnp.ndarray:
 @functools.partial(jax.jit, static_argnames=("bkv", "interpret"))
 def flash_decode_pallas(
     q: jnp.ndarray,        # (B, T, nq, hd) draft/step tokens
-    k: jnp.ndarray,        # (B, Skv, nkv, hd) full cache buffer, as the model stores it
+    k: jnp.ndarray,        # (B, Skv, nkv, hd) full cache buffer, as the model stores it;
+                           # (L, B, Skv, nkv, hd) the layer-stacked cache with ``layer``
     v: jnp.ndarray,
     lengths: jnp.ndarray,  # (B*T,) int32 valid prefix length per token, >= 1
     anc_words: Optional[jnp.ndarray] = None,  # (T,) int32 ancestor bitmasks
     base: Optional[jnp.ndarray] = None,       # (B,) int32 committed-prefix length
-    scales: Optional[jnp.ndarray] = None,     # (2, B, Skv) f32 per-row K/V scales
+    scales: Optional[jnp.ndarray] = None,     # (2, B, Skv) f32 per-row K/V scales (of the layer)
+    layer: Optional[jnp.ndarray] = None,      # int32 scalar: the layer of the stacked cache
     *,
     bkv: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, T, nq, hd = q.shape
-    Skv, nkv = k.shape[1], k.shape[2]
+    Skv, nkv = k.shape[-3], k.shape[-2]
     scale = 1.0 / math.sqrt(hd)
     n_kv = pl.cdiv(Skv, bkv)
     quantized = scales is not None
+    stacked = layer is not None
     if anc_words is None:
         # chain default: all-ones words make the ancestor test vacuous and
         # the kernel bitwise-equal to the pure length-clamped linear kernel
@@ -266,16 +273,21 @@ def flash_decode_pallas(
         # topological-order invariant).
         return jnp.minimum(ki, (len_ref[b * T + t] - 1) // bkv)
 
-    def kv_map(b, t, ki, len_ref, *_):
-        return (b, kv_block(b, t, ki, len_ref), 0, 0)
+    # the stacked cache's layer axis is squeezed out of the block: the
+    # kernel sees the same (1, bkv, nkv, hd) block either way, taken at the
+    # layer the prefetched layer word names
+    lead = (None,) if stacked else ()
+
+    def kv_map(b, t, ki, len_ref, anc_ref, base_ref, *layer_ref):
+        return tuple(r[0] for r in layer_ref) + (b, kv_block(b, t, ki, len_ref), 0, 0)
 
     def qo_map(b, t, ki, *_):
         return (b, t, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, nq, hd), qo_map),
-        pl.BlockSpec((1, bkv, nkv, hd), kv_map),
-        pl.BlockSpec((1, bkv, nkv, hd), kv_map),
+        pl.BlockSpec(lead + (1, bkv, nkv, hd), kv_map),
+        pl.BlockSpec(lead + (1, bkv, nkv, hd), kv_map),
     ]
     operands = [q, k, v]
     if quantized:
@@ -285,14 +297,17 @@ def flash_decode_pallas(
             lambda b, t, ki, len_ref, *_: (0, b, kv_block(b, t, ki, len_ref), 0),
         ))
         operands.append(scales.astype(jnp.float32).reshape(2, B, Skv, 1))
+    words = [lengths, anc_words.astype(jnp.int32), base.astype(jnp.int32)]
+    if stacked:
+        words.append(layer.astype(jnp.int32).reshape(1))
     kern = functools.partial(
         _flash_decode_kernel, bkv=bkv, n_kv=n_kv, scale=scale, T=T,
-        quantized=quantized, paged=False,
+        quantized=quantized, steer_words=int(stacked),
     )
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(words),
             grid=(B, T, n_kv),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, 1, nq, hd), qo_map),
@@ -304,7 +319,7 @@ def flash_decode_pallas(
         ),
         interpret=interpret,
         name="flash_decode",
-    )(lengths, anc_words.astype(jnp.int32), base.astype(jnp.int32), *operands)
+    )(*words, *operands)
 
 
 # ---------------------------------------------------------------------------
@@ -493,7 +508,7 @@ def flash_decode_paged_pallas(
         operands.append(scales.astype(jnp.float32).reshape(2, P_, ps, 1))
     kern = functools.partial(
         _flash_decode_kernel, bkv=ps, n_kv=max_pages, scale=scale, T=T,
-        quantized=quantized, paged=True,
+        quantized=quantized, steer_words=1,
     )
     return pl.pallas_call(
         kern,
@@ -539,13 +554,16 @@ def _as_length_vector(cache_index: jnp.ndarray, B: int, T: int) -> jnp.ndarray:
 
 def flash_decode(
     q: jnp.ndarray,  # (B, T, nq, hd) — model layout (T = 1 for plain decode)
-    k: jnp.ndarray,  # (B, Skv, nkv, hd) cache buffer (already holding this step's K)
+    k: jnp.ndarray,  # (B, Skv, nkv, hd) cache buffer (already holding this step's K),
+                     # or the layer-stacked (L, B, Skv, nkv, hd) cache with ``layer``
     v: jnp.ndarray,
     cache_index: jnp.ndarray,  # scalar | (B,) | (B, T) int32 token position(s)
     *,
     ancestors: Optional[jnp.ndarray] = None,  # (T,) int32 packed ancestor words
     base: Optional[jnp.ndarray] = None,       # (B,) int32 committed-prefix length
-    scales: Optional[jnp.ndarray] = None,     # (2, B, Skv) per-row K/V scales (int8 cache)
+    scales: Optional[jnp.ndarray] = None,     # (2, B, Skv) per-row K/V scales (int8 cache);
+                                              # (2, L, B, Skv) with ``layer``
+    layer: Optional[jnp.ndarray] = None,      # int32 scalar: the layer of a stacked cache
     bkv: Optional[int] = None,                # cache rows per block; None: from the shape
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
@@ -561,10 +579,25 @@ def flash_decode(
     ``ancestors[t]`` (see :class:`repro.core.plans.TreePlan.ancestor_words`).
     Without them every draft row at or below the token's own row is visible —
     the linear-chain behaviour, bit-for-bit.
+
+    With ``layer`` the cache is the scan's layer-stacked buffer and the
+    kernel reads layer ``layer``'s blocks straight out of it (one more
+    prefetched word steering the index maps): bitwise the call on
+    ``k[layer]``, without slicing the layer out.  Only the layer's scale
+    rows, (2, B, Skv), are taken out of stacked ``scales``: the kernel's
+    ``(2, B, Skv, 1)`` view of them pads each row to a lane tile, so a
+    view of the whole stack would be rewritten every layer.
     """
+    if (layer is None) != (k.ndim == 4):
+        raise ValueError(
+            "flash_decode takes a (B, S, nkv, hd) cache, or a layer-stacked "
+            f"(L, B, S, nkv, hd) cache with layer=; got {k.ndim}-D, layer={layer}"
+        )
+    heads = k.ndim - 2
     run = functools.partial(_flash_decode, bkv=bkv, interpret=interpret)
-    args = (q, k, v, jnp.asarray(cache_index, jnp.int32), ancestors, base, scales)
-    return shard_over_heads(run, args, (2, 2, 2, None, None, None, None))
+    args = (q, k, v, jnp.asarray(cache_index, jnp.int32), ancestors, base, scales,
+            None if layer is None else jnp.asarray(layer, jnp.int32))
+    return shard_over_heads(run, args, (2, heads, heads, None, None, None, None, None))
 
 
 def _block_rows(S: int, nkv: int, hd: int, dtype) -> int:
@@ -579,16 +612,18 @@ def _block_rows(S: int, nkv: int, hd: int, dtype) -> int:
     return min(max(8, rows - rows % 8), S)
 
 
-def _flash_decode(q, k, v, cache_index, ancestors, base, scales, *, bkv, interpret):
+def _flash_decode(q, k, v, cache_index, ancestors, base, scales, layer, *, bkv, interpret):
     it = resolve_interpret(interpret)
     B, T, nq, hd = q.shape
-    S, nkv = k.shape[1], k.shape[2]
+    S, nkv = k.shape[-3], k.shape[-2]
     bkv = _block_rows(S, nkv, hd, k.dtype) if bkv is None else min(bkv, S)
     with jax.named_scope("attn.kv_read"):  # the control words; the cache is read in place
         lengths = _as_length_vector(cache_index, B, T)
+        if layer is not None and scales is not None:
+            scales = jax.lax.dynamic_index_in_dim(scales, layer, axis=1, keepdims=False)
     return flash_decode_pallas(
         q, k, v, lengths, anc_words=ancestors, base=base, scales=scales,
-        bkv=bkv, interpret=it,
+        layer=layer, bkv=bkv, interpret=it,
     )
 
 

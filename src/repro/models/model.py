@@ -10,6 +10,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig, ShapeCell
 from repro.models import layers as L
 from repro.models import moe
@@ -310,29 +311,37 @@ class Model:
         agree_sum = jnp.float32(0.0)
         n_moe = max(sum(1 for k in cfg.layer_kinds if k == "moe"), 1)
 
+        # the stacked cache rides in the scan's carry, so the step's output
+        # cache is its (donated) input buffer: contiguous attention layers
+        # write their new rows into the stack and the kernel reads them
+        # there; every other cache takes its layer out and puts it back
         def sb_fn(carry, xs):
-            h, rs, agg = carry
-            p_sb, c_sb = xs
+            h, rs, agg, c_sb = carry
+            p_sb, i = xs
             new_c = {}
             for j, kind in enumerate(pat):
-                h, rs, nc, a = T.apply_layer_decode_spec(
-                    h, rs, p_sb[f"b{j}"], c_sb[f"b{j}"], kind, cfg,
+                h, rs, new_c[f"b{j}"], a = T.apply_layer_decode_stacked(
+                    h, rs, p_sb[f"b{j}"], c_sb[f"b{j}"], kind, cfg, i,
                     lengths, prev_accept, self.moe_apply,
                     decode_apply=self.decode_moe_apply, telemetry=telemetry,
                     tree=tree, pages=pages, commit=commit,
                 )
-                new_c[f"b{j}"] = nc
                 agg = agg + a
-            return (h, rs, agg), new_c
+            return (h, rs, agg, new_c), None
 
         new_cache: Params = {"scan": {}, "rest": []}
-        # "layers" names the scan's own slicing and restacking of the cache
         with jax.named_scope("layers"):
             if n_sb:
-                (x, route_src, agree_sum), new_scan = jax.lax.scan(
-                    sb_fn, (x, route_src, agree_sum), (params["blocks"]["scan"], cache["scan"])
+                # once per trace: which path each scanned layer's cache took
+                in_place = n_sb * sum(
+                    T.kv_in_place(kind, cfg, cache["scan"][f"b{j}"]) for j, kind in enumerate(pat)
                 )
-                new_cache["scan"] = new_scan
+                obs.count("decode.kv_in_place", in_place)
+                obs.count("decode.kv_sliced", n_sb * len(pat) - in_place)
+                (x, route_src, agree_sum, new_cache["scan"]), _ = jax.lax.scan(
+                    sb_fn, (x, route_src, agree_sum, cache["scan"]),
+                    (params["blocks"]["scan"], jnp.arange(n_sb, dtype=jnp.int32)),
+                )
             kinds = cfg.layer_kinds
             for j, (p, c) in enumerate(zip(params["blocks"]["rest"], cache["rest"])):
                 kind = kinds[n_sb * len(pat) + j]

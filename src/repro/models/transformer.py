@@ -464,6 +464,40 @@ def apply_layer_decode(
     return x, route_src, new_cache, aux
 
 
+# the leaves of a contiguous KV cache: int8 rows and their per-row scales
+_KV_LEAVES = ("k", "v", "ks", "vs")
+
+
+def kv_in_place(kind: str, cfg: ModelConfig, cache: Params) -> bool:
+    """Whether the decode scan writes and reads this layer's KV inside its
+    layer-stacked cache: contiguous (not paged), full-attention (not
+    rolling) caches, chain or tree drafts.  Every other cache takes its
+    layer out of the stack and puts it back (:func:`apply_layer_decode_stacked`)."""
+    window = cfg.local_window if (kind == "local" or cfg.attention_kind == "local") else 0
+    return kind in ("attn", "local", "moe") and "k" in cache and not window
+
+
+def apply_layer_decode_stacked(x, route_src, p, cache, kind, cfg, layer, *args, **kw):
+    """:func:`apply_layer_decode_spec` on layer ``layer`` (int32 scalar) of
+    the decode scan's layer-stacked ``cache``, which comes back stacked.
+
+    Where :func:`kv_in_place` holds, the KV leaves stay in the stack: the
+    layer's new rows are written at ``[layer, b, row]`` and the kernel reads
+    the layer's blocks out of the stack, so each layer moves only its new
+    rows.  Every other leaf (the MoE plan rows; every leaf of a rolling,
+    paged or recurrent cache) is taken out of the stack and put back.
+    """
+    keep = _KV_LEAVES if kv_in_place(kind, cfg, cache) else ()
+    own = {n: c if n in keep else jax.lax.dynamic_index_in_dim(c, layer, keepdims=False)
+           for n, c in cache.items()}
+    x, route_src, nc, agree = apply_layer_decode_spec(
+        x, route_src, p, own, kind, cfg, *args, layer=layer if keep else None, **kw
+    )
+    new = {n: c if n in keep else jax.lax.dynamic_update_index_in_dim(cache[n], c, layer, 0)
+           for n, c in nc.items()}
+    return x, route_src, new, agree
+
+
 def apply_layer_decode_spec(
     x: jnp.ndarray,  # (B, T, d) — T draft tokens per sequence, one launch
     route_src: Optional[jnp.ndarray],
@@ -480,6 +514,7 @@ def apply_layer_decode_spec(
     tree: Optional[TreePlan] = None,
     pages: Optional[jnp.ndarray] = None,  # (B, max_pages) int32 block table
     commit: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # (dst, src) (B, Tc)
+    layer: Optional[jnp.ndarray] = None,  # int32 scalar: the KV leaves are layer-stacked
 ):
     """Multi-token (speculative) ragged decode for one layer.
 
@@ -517,6 +552,11 @@ def apply_layer_decode_spec(
     rolling-window layers (the fused commit maps compose with modulo
     addressing); the legacy non-paged path keeps the chain-only
     restriction.
+
+    With ``layer`` the KV leaves of ``cache`` (``k``, ``v``, ``ks``,
+    ``vs``) are the decode scan's layer-stacked buffers, written and read in
+    place at that layer (see :func:`apply_layer_decode_stacked`; contiguous
+    full-attention caches only).
 
     Returns ``(x, route_src, new_cache, plan_agreement)`` where
     ``plan_agreement`` is the stale-vs-fresh top-k overlap (0 when not a MoE
@@ -559,13 +599,15 @@ def apply_layer_decode_spec(
                 xn, p["attn"], cfg, cache, lengths, tree, pages
             )
         elif tree is not None:
-            a, new_cache = _decode_attn_prefix_tree(xn, p["attn"], cfg, cache, lengths, tree)
+            a, new_cache = _decode_attn_prefix_tree(
+                xn, p["attn"], cfg, cache, lengths, tree, layer
+            )
         elif window:
             a, new_cache = _decode_attn_rolling_spec(xn, p["attn"], cfg, cache, lengths, window)
         elif paged:
             a, new_cache = _decode_attn_paged_spec(xn, p["attn"], cfg, cache, lengths, pages)
         else:
-            a, new_cache = _decode_attn_prefix_spec(xn, p["attn"], cfg, cache, lengths)
+            a, new_cache = _decode_attn_prefix_spec(xn, p["attn"], cfg, cache, lengths, layer)
         with jax.named_scope("attn.out"):
             h = _res(x + a)
         with jax.named_scope("ffn"):
@@ -675,12 +717,44 @@ def _deq(c: jnp.ndarray, s: Optional[jnp.ndarray]) -> jnp.ndarray:
     return c.astype(jnp.float32) * s[..., None, None].astype(jnp.float32)
 
 
+def _write_kv_rows(cache: Params, k, v, rows, layer=None) -> Params:
+    """Write the launch's new K/V rows (B, T, nkv, hd) at cache rows ``rows``
+    (B, T) of a contiguous cache (quantized per row when it is int8); with
+    ``layer`` into the layer-stacked cache at ``[layer, b, row]``, in place.
+    Returns the cache's new leaves."""
+    at = (jnp.arange(k.shape[0])[:, None], rows)
+    if layer is not None:
+        at = (layer,) + at
+    nc = {}
+    if "ks" in cache:
+        k, v, ksr, vsr = _quant_kv_rows(k, v)
+        nc["ks"] = cache["ks"].at[at].set(ksr)
+        nc["vs"] = cache["vs"].at[at].set(vsr)
+    nc["k"] = cache["k"].at[at].set(k.astype(cache["k"].dtype))
+    nc["v"] = cache["v"].at[at].set(v.astype(cache["v"].dtype))
+    return nc
+
+
+def _kv_scales(nc: Params) -> Optional[jnp.ndarray]:
+    """The (2, ...) K/V scale rows the kernel prefetches, or None unquantized."""
+    return jnp.stack([nc["ks"], nc["vs"]]) if "ks" in nc else None
+
+
+def _kv_views(nc: Params, layer=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The layer's (B, S, nkv, hd) K and V, dequantized, for the masked-jnp
+    paths (which slice a stacked cache: the kernel reads it in place)."""
+    if layer is not None:
+        nc = {n: c[layer] for n, c in nc.items()}
+    return _deq(nc["k"], nc.get("ks")), _deq(nc["v"], nc.get("vs"))
+
+
 def _decode_attn_prefix_spec(
     xn: jnp.ndarray,  # (B, T, d)
     p: Params,
     cfg: ModelConfig,
     cache: Params,
     lengths: jnp.ndarray,  # (B,)
+    layer: Optional[jnp.ndarray] = None,  # int32 scalar: the cache is layer-stacked
 ) -> Tuple[jnp.ndarray, Params]:
     """T-token attention over per-token valid prefixes [0, lengths[b] + t].
 
@@ -692,25 +766,19 @@ def _decode_attn_prefix_spec(
         pos = _spec_positions(lengths, T)
         q, k, v = L._qkv(xn, p, cfg, pos)
     with jax.named_scope("attn.kv_write"):
-        bidx = jnp.arange(B)[:, None]
-        cks = cvs = None
-        if "ks" in cache:
-            k, v, ksr, vsr = _quant_kv_rows(k, v)
-            cks = cache["ks"].at[bidx, pos].set(ksr)
-            cvs = cache["vs"].at[bidx, pos].set(vsr)
-        ck = cache["k"].at[bidx, pos].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[bidx, pos].set(v.astype(cache["v"].dtype))
+        nc = _write_kv_rows(cache, k, v, pos, layer)
     with jax.named_scope("attn.kernel"):
         if use_kernels(cfg):
             from repro.kernels.flash_attention import flash_decode
 
-            scl = None if cks is None else jnp.stack([cks, cvs])
-            out = flash_decode(q, ck, cv, pos, scales=scl)  # (B, T, nq, hd)
+            out = flash_decode(
+                q, nc["k"], nc["v"], pos, scales=_kv_scales(nc), layer=layer
+            )  # (B, T, nq, hd)
         else:
-            S = ck.shape[1]
+            ckf, cvf = _kv_views(nc, layer)
+            S = ckf.shape[1]
             hd, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
             groups = cfg.num_heads // nkv
-            ckf, cvf = _deq(ck, cks), _deq(cv, cvs)
             valid = jnp.arange(S)[None, None, :] <= pos[:, :, None]  # (B, T, S)
             scale = 1.0 / math.sqrt(hd)
             qg = q.reshape(B, T, nkv, groups, hd)
@@ -721,9 +789,6 @@ def _decode_attn_prefix_spec(
             out = out.reshape(B, T, cfg.num_heads, hd).astype(xn.dtype)
     with jax.named_scope("attn.out"):
         y = jnp.einsum("btnh,nhd->btd", out, p["wo"].astype(out.dtype))
-    nc = {"k": ck, "v": cv}
-    if cks is not None:
-        nc["ks"], nc["vs"] = cks, cvs
     return y, nc
 
 
@@ -734,6 +799,7 @@ def _decode_attn_prefix_tree(
     cache: Params,
     lengths: jnp.ndarray,  # (B,) committed-prefix length per sequence
     tree: "TreePlan",
+    layer: Optional[jnp.ndarray] = None,  # int32 scalar: the cache is layer-stacked
 ) -> Tuple[jnp.ndarray, Params]:
     """Ancestor-masked T-node attention: the tree generalization of
     :func:`_decode_attn_prefix_spec`.
@@ -750,28 +816,20 @@ def _decode_attn_prefix_tree(
     rows = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # (B, T)
     pos = lengths[:, None] + depths[None, :]  # rotary positions
     q, k, v = L._qkv(xn, p, cfg, pos)
-    bidx = jnp.arange(B)[:, None]
-    cks = cvs = None
-    if "ks" in cache:
-        k, v, ksr, vsr = _quant_kv_rows(k, v)
-        cks = cache["ks"].at[bidx, rows].set(ksr)
-        cvs = cache["vs"].at[bidx, rows].set(vsr)
-    ck = cache["k"].at[bidx, rows].set(k.astype(cache["k"].dtype))
-    cv = cache["v"].at[bidx, rows].set(v.astype(cache["v"].dtype))
+    nc = _write_kv_rows(cache, k, v, rows, layer)
     if use_kernels(cfg):
         from repro.kernels.flash_attention import flash_decode
 
-        scl = None if cks is None else jnp.stack([cks, cvs])
         out = flash_decode(
-            q, ck, cv, lengths,
+            q, nc["k"], nc["v"], lengths,
             ancestors=jnp.asarray(tree.ancestor_words(), jnp.int32),
-            base=lengths, scales=scl,
+            base=lengths, scales=_kv_scales(nc), layer=layer,
         )  # (B, T, nq, hd)
     else:
-        S = ck.shape[1]
+        ckf, cvf = _kv_views(nc, layer)
+        S = ckf.shape[1]
         hd, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
         groups = cfg.num_heads // nkv
-        ckf, cvf = _deq(ck, cks), _deq(cv, cvs)
         table = jnp.asarray(tree.ancestor_table(), bool)  # (T, T)
         u = jnp.arange(S)[None, :] - lengths[:, None]  # (B, S) draft-row index
         in_draft = (u >= 0) & (u < T)
@@ -787,9 +845,6 @@ def _decode_attn_prefix_tree(
         out = jnp.einsum("bngts,bsnh->btngh", w, cvf.astype(jnp.float32))
         out = out.reshape(B, T, cfg.num_heads, hd).astype(xn.dtype)
     y = jnp.einsum("btnh,nhd->btd", out, p["wo"].astype(out.dtype))
-    nc = {"k": ck, "v": cv}
-    if cks is not None:
-        nc["ks"], nc["vs"] = cks, cvs
     return y, nc
 
 
@@ -813,25 +868,17 @@ def _decode_attn_rolling_spec(
     assert T <= W, "draft length must not exceed the rolling window"
     pos = _spec_positions(lengths, T)
     q, k, v = L._qkv(xn, p, cfg, pos)
-    bidx = jnp.arange(B)[:, None]
-    slots = jnp.remainder(pos, W)
-    cks = cvs = None
-    if "ks" in cache:
-        k, v, ksr, vsr = _quant_kv_rows(k, v)
-        cks = cache["ks"].at[bidx, slots].set(ksr)
-        cvs = cache["vs"].at[bidx, slots].set(vsr)
-    ck = cache["k"].at[bidx, slots].set(k.astype(cache["k"].dtype))
-    cv = cache["v"].at[bidx, slots].set(v.astype(cache["v"].dtype))
+    nc = _write_kv_rows(cache, k, v, jnp.remainder(pos, W))
     limit = min(window, W) if window else W
     if cfg.decode_plane and use_kernels(cfg):
         from repro.kernels.flash_attention import flash_decode_window
 
-        scl = None if cks is None else jnp.stack([cks, cvs])
-        out = flash_decode_window(q, ck, cv, lengths, window=limit, scales=scl)
+        out = flash_decode_window(q, nc["k"], nc["v"], lengths, window=limit,
+                                  scales=_kv_scales(nc))
     else:
         hd, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
         groups = cfg.num_heads // nkv
-        ckf, cvf = _deq(ck, cks), _deq(cv, cvs)
+        ckf, cvf = _kv_views(nc)
         head = pos[:, -1]  # (B,) last written absolute position
         slot = jnp.arange(W)
         write = jnp.remainder(head, W)
@@ -850,9 +897,6 @@ def _decode_attn_rolling_spec(
         out = jnp.einsum("bngts,bsnh->btngh", w, cvf.astype(jnp.float32))
         out = out.reshape(B, T, cfg.num_heads, hd).astype(xn.dtype)
     y = jnp.einsum("btnh,nhd->btd", out, p["wo"].astype(out.dtype))
-    nc = {"k": ck, "v": cv}
-    if cks is not None:
-        nc["ks"], nc["vs"] = cks, cvs
     return y, nc
 
 

@@ -255,3 +255,45 @@ def test_flash_decode_kernels_match_oracle(kind, quant):
         want = ref.flash_decode(q, k, v, idx, scales=scl, **tree)
         assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+# the decode scan's stacked cache: (T, ragged lengths, int8, tree)
+STACKED = {"chain1": (1, False, False), "chain4": (4, False, False),
+           "ragged": (2, False, False), "int8": (2, True, False), "tree": (4, False, True)}
+
+
+@pytest.mark.parametrize("case", list(STACKED))
+def test_flash_decode_stacked_layer_bitwise(case):
+    """flash_decode on the layer-stacked (L, B, S, nkv, hd) cache with
+    ``layer=i`` equals the call on ``cache[i]`` bit for bit, and reads no
+    other layer (they hold NaN)."""
+    from repro.kernels.flash_attention import flash_decode
+
+    T, quant, tree = STACKED[case]
+    rng = np.random.default_rng(len(case))
+    Ln, i, B, nq, nkv, hd, S = 3, 1, 3, 8, 2, 16, 37
+    q = jnp.asarray(rng.standard_normal((B, T, nq, hd)), jnp.float32)
+    (k, ks), (v, vs) = (_kv(rng, (Ln, B, S, nkv, hd), quant) for _ in range(2))
+    idx = jnp.asarray([0, 13, S - T] if case == "ragged" else [9, 9, 9], jnp.int32)
+    kw = dict(ancestors=jnp.asarray(TREE4, jnp.int32), base=idx) if tree else {}
+    scl = None if ks is None else jnp.stack([ks, vs])  # (2, L, B, S)
+    want = flash_decode(q, k[i], v[i], idx, scales=None if scl is None else scl[:, i],
+                        bkv=8, interpret=True, **kw)
+    other = jnp.arange(Ln)[:, None, None] != i  # (L, 1, 1): poison every other layer
+    if quant:
+        scl = jnp.where(other[None], jnp.nan, scl)
+    else:
+        k, v = (jnp.where(other[..., None, None], jnp.nan, c) for c in (k, v))
+    got = flash_decode(q, k, v, idx, scales=scl, layer=jnp.int32(i), bkv=8,
+                       interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_flash_decode_layer_needs_stacked_cache():
+    from repro.kernels.flash_attention import flash_decode
+
+    q, c = jnp.zeros((1, 1, 2, 8)), jnp.zeros((1, 8, 1, 8))
+    with pytest.raises(ValueError, match="layer"):
+        flash_decode(q, c, c, 0, layer=0, interpret=True)
+    with pytest.raises(ValueError, match="layer"):
+        flash_decode(q, c[None], c[None], 0, interpret=True)

@@ -12,6 +12,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import obs
 
@@ -183,3 +184,36 @@ def test_decode_step_carries_the_named_scopes():
                   "attn.kernel", "attn.out", "ffn", "head"):
         assert re.search(rf'loc\("([^"]*/)?{re.escape(scope)}/', text), scope
     assert "spec_step" in text
+
+
+# (preset, overrides) -> (decode.kv_in_place, decode.kv_sliced) counted in
+# one trace: scanned layers only, the unstacked "rest" layers in neither
+KV_PATHS = {
+    "dense": (("qwen3-32b", {}), (2, 0)),
+    "attn_local_rest": (("qwen3-32b", dict(block_pattern=("attn", "local"), num_layers=5,
+                                           local_window=8)), (2, 2)),
+    "paged": (("qwen3-32b", dict(block_pattern=("attn", "local"), num_layers=4,
+                                 local_window=8, paged=True, page_size=8)), (0, 4)),
+    "recurrent": (("recurrentgemma-2b", dict(num_layers=6)), (0, 6)),
+}
+
+
+@pytest.mark.parametrize("case", list(KV_PATHS))
+def test_decode_trace_counts_the_kv_path_of_each_scanned_layer(case):
+    from repro.configs import get_smoke_config
+    from repro.models import transformer as T
+    from repro.models.model import Model
+
+    (preset, over), want = KV_PATHS[case]
+    cfg = dataclasses.replace(get_smoke_config(preset), **over)
+    model = Model(cfg)
+    B, max_len = 2, 16
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(B, max_len))
+    kw = {"pages": T.identity_page_table(cfg, B, max_len)} if cfg.paged else {}
+    names = ("decode.kv_in_place", "decode.kv_sliced")
+    before = [obs.snapshot()["counters"].get(n, 0) for n in names]
+    jax.eval_shape(lambda p, c: model.decode_tokens(
+        p, c, jnp.zeros((B, 1), jnp.int32), jnp.zeros((B,), jnp.int32), **kw), params, cache)
+    after = [obs.snapshot()["counters"].get(n, 0) for n in names]
+    assert tuple(a - b for a, b in zip(after, before)) == want

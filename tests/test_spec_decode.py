@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.model import Model
 
@@ -320,6 +321,94 @@ def test_decode_tokens_supports_recurrent_layers_at_width_one():
             np.asarray(la), np.asarray(lb[:, 0]), rtol=1e-5, atol=1e-5, err_msg=f"step {i}"
         )
         toks = jnp.argmax(la, -1).astype(jnp.int32)
+
+
+# the decode scan keeps the stacked cache in its carry; each case against the
+# scan that sliced every layer's cache out of the stack and restacked it:
+# (preset, overrides, kernels, draft tree)
+STACKED = {
+    "attn_local_jnp": ("qwen3-32b", dict(block_pattern=("attn", "local"), num_layers=4,
+                                         local_window=8), False, None),
+    "attn_local_kernel": ("qwen3-32b", dict(block_pattern=("attn", "local"), num_layers=4,
+                                            local_window=8, decode_plane=True), True, None),
+    "int8_kernel": ("qwen3-32b", dict(kv_dtype="int8"), True, None),
+    "tree_kernel": ("qwen3-32b", dict(spec_tokens=4), True, (2, 1)),
+    "paged_kernel": ("qwen3-32b", dict(block_pattern=("attn", "local"), num_layers=4,
+                                       local_window=8, paged=True, page_size=8), True, None),
+    "moe_plane_jnp": ("qwen3-moe-235b-a22b", dict(decode_plane=True, spec_tokens=2), False, None),
+    "recurrent_jnp": ("recurrentgemma-2b", dict(num_layers=6, decode_plane=True), False, None),
+}
+
+
+def _random_cache(cfg, cache, key):
+    """Every leaf drawn at random: KV rows, positive scales, valid plan ids."""
+    leaves, tdef = jax.tree_util.tree_flatten_with_path(cache)
+    out = []
+    for (path, leaf), k in zip(leaves, jax.random.split(key, len(leaves))):
+        name = path[-1].key
+        if name == "plan_e":
+            out.append(jax.random.randint(k, leaf.shape, 0, cfg.num_experts, leaf.dtype))
+        elif name in ("ks", "vs", "pks", "pvs", "plan_w"):
+            out.append(jax.random.uniform(k, leaf.shape, leaf.dtype, 0.01, 0.1))
+        elif leaf.dtype == jnp.int8:
+            out.append(jax.random.randint(k, leaf.shape, -127, 128, jnp.int32).astype(jnp.int8))
+        else:
+            out.append(jax.random.normal(k, leaf.shape, leaf.dtype))
+    return jax.tree_util.tree_unflatten(tdef, out)
+
+
+def _sliced_decode_tokens(model, params, cache, tokens, lengths, prev_accept, **kw):
+    """``decode_tokens`` with the cache in the scan's xs and ys: each layer's
+    cache sliced out of the stack and restacked (no ``rest`` layers)."""
+    cfg = model.cfg
+    pat = cfg.block_pattern
+    x = L.embed(tokens, params["embed"], jnp.dtype(cfg.dtype))
+    x = x * jnp.sqrt(jnp.float32(cfg.d_model)).astype(x.dtype)
+
+    def sb_fn(carry, xs):
+        h, rs = carry
+        p_sb, c_sb = xs
+        new_c = {}
+        for j, kind in enumerate(pat):
+            h, rs, new_c[f"b{j}"], _ = T.apply_layer_decode_spec(
+                h, rs, p_sb[f"b{j}"], c_sb[f"b{j}"], kind, cfg, lengths, prev_accept,
+                model.moe_apply, decode_apply=model.decode_moe_apply, **kw,
+            )
+        return (h, rs), new_c
+
+    (x, _), scan = jax.lax.scan(sb_fn, (x, x), (params["blocks"]["scan"], cache["scan"]))
+    return model.logits(params, x), {"scan": scan, "rest": cache["rest"]}
+
+
+@pytest.mark.parametrize("case", list(STACKED))
+def test_decode_tokens_stacked_cache_matches_sliced_scan(case):
+    """Logits and the whole new cache are bitwise those of the scan that
+    sliced each layer's cache out and restacked it: contiguous attention
+    in place, rolling, paged, plan and recurrent leaves taken out and put
+    back."""
+    from repro.core.plans import TreePlan
+
+    preset, over, kernels, branching = STACKED[case]
+    cfg = dataclasses.replace(get_smoke_config(preset), use_pallas=kernels, **over)
+    assert cfg.num_layers % len(cfg.block_pattern) == 0
+    model = Model(cfg)
+    B, max_len, Tn = 2, 24, max(cfg.spec_tokens, 1)
+    params = model.init(jax.random.PRNGKey(0))
+    cache = _random_cache(cfg, model.init_cache(B, max_len), jax.random.PRNGKey(1))
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (B, Tn), 0, cfg.vocab_size)
+    lengths = jnp.asarray([3, max_len - Tn - 1], jnp.int32)
+    prev = jnp.asarray([0, Tn - 1], jnp.int32)
+    kw = {}
+    if branching:
+        kw["tree"] = TreePlan.from_branching(list(branching)).validate()
+    if cfg.paged:
+        kw["pages"] = T.identity_page_table(cfg, B, max_len)
+    got_l, got_c = jax.jit(lambda c: model.decode_tokens(params, c, tokens, lengths, prev, **kw))(cache)
+    want_l, want_c = jax.jit(
+        lambda c: _sliced_decode_tokens(model, params, c, tokens, lengths, prev, **kw))(cache)
+    np.testing.assert_array_equal(np.asarray(got_l), np.asarray(want_l))
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+                 got_c, want_c)
 
 
 def test_serve_verify_rollback_equals_sequential_greedy():

@@ -1,7 +1,8 @@
 """Compile rehearsals: every Pallas kernel the served path reaches on TPU,
 lowered and compiled for a described (unattached) TPU v5e at the widths of
 the ``qwen3-moe-235b-a22b-1chip`` preset; ``flash_decode`` also at the dense
-cell's shapes (with a guard on its program) and at other head widths.
+cell's shapes (with a guard on its program) and at other head widths, and
+the dense cell's whole decode step (with a guard on its cache traffic).
 
 Nothing runs: these compiles catch what interpret mode cannot — block shapes
 that break the (8, 128) tiling rule, scoped-VMEM overflows, SMEM tables that
@@ -113,6 +114,47 @@ def test_flash_decode_reads_cache_in_place(one_chip, quant):
         if math.prod(int(d) for d in dims.split(",")) >= B_ * S_ * nkv * hd
     ]
     assert not moved, moved
+
+
+@pytest.mark.parametrize("kv", ["", "int8"], ids=["bf16", "int8"])
+def test_decode_step_keeps_the_stacked_cache_in_place(topo, monkeypatch, kv):
+    """The whole spec serve step at the dense cell's widths (32 slots x
+    3,073 rows, 64 / 8 heads of 128, spec width 1; 2 of its 8 layers): no
+    copy, transpose, pad, dynamic-slice or dynamic-update-slice of one
+    layer's cache or more in the optimised HLO (the layer scan neither
+    slices the cache nor restacks it), and every cache leaf is aliased to
+    its output (the donated buffer is the new cache: no whole-cache copy)."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    import repro.kernels
+    from repro.configs.base import ShapeCell
+    from repro.launch.steps import build_spec_serve_step
+
+    monkeypatch.setattr(repro.kernels, "on_tpu", lambda: True)  # the served path's kernels
+    B_, S_, nkv, hd = 32, 3073, 8, 128
+    cfg = dataclasses.replace(get_config("qwen3-32b"), num_layers=2, vocab_size=18992,
+                              param_dtype="bfloat16", spec_tokens=1, kv_dtype=kv)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    step = build_spec_serve_step(cfg, mesh, ShapeCell("d", S_, B_, "decode"))
+    hlo = step.jit().lower(*step.abstract_inputs).compile().as_text()
+    assert "tpu_custom_call" in hlo, "no Pallas kernel in the program"
+    moved = [  # cache arrays: the only ones with a 3,073-row axis (a layer's weights are larger)
+        line.strip()[:200] for line in hlo.splitlines()
+        for dims in re.findall(
+            r"= \w+\[([\d,]+)\]\S* (?:copy|transpose|pad|dynamic-slice|dynamic-update-slice)\(",
+            line)
+        if S_ in (shape := [int(d) for d in dims.split(",")])
+        and math.prod(shape) >= B_ * S_ * nkv * hd
+    ]
+    assert not moved, moved
+    cache = {int(n) for n in re.findall(r"%cache\S* = \S+ parameter\((\d+)\)", hlo)}
+    assert len(cache) == len(jax.tree.leaves(step.abstract_inputs[1])) == (4 if kv else 2)
+    aliased = {int(n) for n in re.findall(r"\{\d+\}: \((\d+), \{\}", hlo.splitlines()[0])}
+    assert cache <= aliased, (sorted(cache), sorted(aliased))
 
 
 # (nq, nkv, hd): MHA, hd 96, qwen3-moe-235b-a22b per chip of four (its 4 KV
